@@ -151,13 +151,12 @@ def fd_grad_worst_rel_err(t: int = 48) -> float:
     "FD-correct" means."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.energy.markets import MarketParams
     from repro.tune import (PolicyParams, dispatch_coupling_from_grid,
                             soft_objective)
 
-    with enable_x64():
+    with jax.enable_x64(True):
         grid = build_grid([MarketParams(n_hours=t, seed=s)
                            for s in range(2)],
                           [make_system(0.5 * t * 80.0, 1.0, float(t))],

@@ -15,6 +15,7 @@ from benchmarks import (bench_dispatch, bench_faults, bench_fleet,
                         bench_runtime, bench_tune, bench_tune_coupled,
                         bench_workload, paper_figures)
 from benchmarks.common import ARTIFACTS
+from repro.compile_cache import use_compile_cache
 
 
 def main() -> int:
@@ -23,6 +24,7 @@ def main() -> int:
     ap.add_argument("--skip-runtime", action="store_true",
                     help="paper figures only (fast)")
     args = ap.parse_args()
+    use_compile_cache()
 
     suites = dict(paper_figures.ALL)
     if not args.skip_runtime:

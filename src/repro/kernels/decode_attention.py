@@ -43,11 +43,11 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref,
     q = q_ref[0, 0].astype(jnp.float32)                # [R, dh]
     k = k_ref[0, 0].astype(jnp.float32)                # [bkv, dh]
     v = v_ref[0, 0]                                    # [bkv, dh]
-    valid = valid_ref[0] != 0                          # [bkv] int8 -> bool
+    valid = valid_ref[...] != 0                        # [1, bkv] int8 -> bool
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    s = jnp.where(valid[None, :], s, NEG_INF)          # [R, bkv]
+    s = jnp.where(valid, s, NEG_INF)                   # [R, bkv]
 
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
@@ -70,7 +70,8 @@ def decode_attention_bgrd(q: jax.Array, k: jax.Array, v: jax.Array,
                           valid: jax.Array, *, bkv: int,
                           interpret: bool) -> jax.Array:
     """Core pallas_call. q: [B,G,R,Dh]; k,v: [B,G,W,Dh] (W a multiple of
-    ``bkv``); valid: [B,W] int8. Returns [B,G,R,Dh]."""
+    ``bkv``); valid: [B,1,W] int8 (the unit axis keeps the mask block's
+    last two dims tile-legal). Returns [B,G,R,Dh]."""
     b, g, r, dh = q.shape
     w = k.shape[2]
     n_kv = w // bkv
@@ -85,7 +86,7 @@ def decode_attention_bgrd(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, 1, r, dh), lambda b_, g_, j: (b_, g_, 0, 0)),
             pl.BlockSpec((1, 1, bkv, dh), lambda b_, g_, j: (b_, g_, j, 0)),
             pl.BlockSpec((1, 1, bkv, dh), lambda b_, g_, j: (b_, g_, j, 0)),
-            pl.BlockSpec((1, bkv), lambda b_, g_, j: (b_, j)),
+            pl.BlockSpec((None, 1, bkv), lambda b_, g_, j: (b_, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, 1, r, dh),
                                lambda b_, g_, j: (b_, g_, 0, 0)),

@@ -11,8 +11,11 @@ time recurrence *inside* each block with a last-decisive-event trick:
 is "state of the most recent decisive sample". Encoding each decisive
 sample as ev_t = 2 t + on_t (on/off are mutually exclusive since
 p_on <= p_off) and taking a running max over time yields, per element, the
-index *and* decision of the latest event in one `cummax` — no serial loop;
-samples before the first event inherit the carry from the previous block.
+index *and* decision of the latest event in one prefix max — no serial
+loop; samples before the first event inherit the carry from the previous
+block. The prefix max is log-depth shift-and-`maximum` over sublanes
+(`kernels.ref.doubling_prefix`): Mosaic has no `cummax` lowering, and
+max is exact, so any association gives the same bits.
 
 Layout: time-major [T, B] blocks (rows ride the 128-lane axis, the running
 max runs along sublanes). Grid = (n_row_blocks, n_time_blocks) with time
@@ -34,7 +37,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ref import FleetScanOut
+from repro.kernels.ref import FleetScanOut, doubling_prefix
 
 
 def _fleet_kernel(p_ref, pon_ref, poff_ref, lvl_ref, idle_ref,   # inputs
@@ -49,7 +52,7 @@ def _fleet_kernel(p_ref, pon_ref, poff_ref, lvl_ref, idle_ref,   # inputs
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     p = p_ref[...].astype(jnp.float32)              # [bt, bb] time-major
-    pon = pon_ref[...]                              # [bb]
+    pon = pon_ref[...]                              # [1, bb]
     poff = poff_ref[...]
     lvl = lvl_ref[...]
     idle = idle_ref[...]
@@ -57,31 +60,30 @@ def _fleet_kernel(p_ref, pon_ref, poff_ref, lvl_ref, idle_ref,   # inputs
     tloc = jax.lax.broadcasted_iota(jnp.int32, p.shape, 0)
     valid = (ti * block_t + tloc) < t_total         # [bt, bb] T-padding mask
 
-    on_ev = (p <= pon[None, :]) & valid
-    off_ev = (p > poff[None, :]) & valid
+    on_ev = (p <= pon) & valid
+    off_ev = (p > poff) & valid
     # ev = 2t for an off event, 2t+1 for an on event, -1 otherwise; the
     # running max is then the latest decisive event and its low bit the
     # state it imposed.
     ev = jnp.where(on_ev | off_ev,
                    2 * tloc + on_ev.astype(jnp.int32), -1)
-    last = jax.lax.cummax(ev, axis=0)               # [bt, bb]
+    last = doubling_prefix(ev, jnp.maximum, -1, axis=0)  # [bt, bb]
 
-    carry = state_scr[...]                          # [bb] in {0, 1}
+    carry = state_scr[...]                          # [1, bb] in {0, 1}
     on = jnp.where(last >= 0, (last & 1).astype(jnp.float32),
-                   carry[None, :])                  # [bt, bb]
-    on_prev = jnp.concatenate([carry[None, :], on[:-1]], axis=0)
+                   carry)                           # [bt, bb]
+    on_prev = jnp.concatenate([carry, on[:-1]], axis=0)
     starts = jnp.maximum(on - on_prev, 0.0)         # only at valid samples
 
     vf = valid.astype(jnp.float32)
-    cap = lvl[None, :] + (1.0 - lvl[None, :]) * on
-    draw = cap + idle[None, :] * (1.0 - cap)
-    acc_scr[0, :] += jnp.sum(draw * p * vf, axis=0)
-    acc_scr[1, :] += jnp.sum(cap * vf, axis=0)
-    acc_scr[2, :] += jnp.sum(starts, axis=0)
-    acc_scr[3, :] += jnp.sum(starts * p, axis=0)
-    # events on invalid samples are masked, so on[-1] is the state at the
-    # last valid sample even in a partially (or fully) padded block.
-    state_scr[...] = on[-1]
+    cap = lvl + (1.0 - lvl) * on
+    draw = cap + idle * (1.0 - cap)
+    acc_scr[...] += jnp.concatenate(
+        [jnp.sum(v, axis=0, keepdims=True)
+         for v in (draw * p * vf, cap * vf, starts, starts * p)], axis=0)
+    # events on invalid samples are masked, so the last row is the state
+    # at the last valid sample even in a partially (or fully) padded block.
+    state_scr[...] = on[block_t - 1:]
 
     @pl.when(ti == n_t_blocks - 1)
     def _finish():
@@ -97,7 +99,8 @@ def _fleet_scan_padded(p_tm: jax.Array, pon: jax.Array, poff: jax.Array,
                        interpret: bool) -> jax.Array:
     """Core pallas_call over padded, time-major inputs.
 
-    p_tm: [T*, B*] (block multiples); params: [B*]. Returns [4, B*].
+    p_tm: [T*, B*] (block multiples); params: [1, B*] (2-D: Mosaic tiles
+    a 1-D operand differently from XLA). Returns [4, B*].
     """
     t_pad, b_pad = p_tm.shape
     nb, nt = b_pad // block_b, t_pad // block_t
@@ -109,14 +112,10 @@ def _fleet_scan_padded(p_tm: jax.Array, pon: jax.Array, poff: jax.Array,
         grid=(nb, nt),
         in_specs=[
             pl.BlockSpec((block_t, block_b), lambda bi, ti: (ti, bi)),
-            pl.BlockSpec((block_b,), lambda bi, ti: (bi,)),
-            pl.BlockSpec((block_b,), lambda bi, ti: (bi,)),
-            pl.BlockSpec((block_b,), lambda bi, ti: (bi,)),
-            pl.BlockSpec((block_b,), lambda bi, ti: (bi,)),
-        ],
+        ] + [pl.BlockSpec((1, block_b), lambda bi, ti: (0, bi))] * 4,
         out_specs=pl.BlockSpec((4, block_b), lambda bi, ti: (0, bi)),
         out_shape=jax.ShapeDtypeStruct((4, b_pad), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_b,), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((1, block_b), jnp.float32),
                         pltpu.VMEM((4, block_b), jnp.float32)],
         interpret=interpret,
     )(p_tm, pon, poff, lvl, idle)
@@ -156,7 +155,7 @@ def fleet_scan(prices: jax.Array, p_on: jax.Array, p_off: jax.Array,
     p_tm = jnp.pad(p.T, ((0, pad_t), (0, pad_b)))    # [T*, B*] time-major
     def _param(v):
         return jnp.pad(jnp.broadcast_to(jnp.asarray(v, jnp.float32), (b,)),
-                       (0, pad_b))
+                       (0, pad_b))[None, :]
     out = _fleet_scan_padded(
         p_tm, _param(p_on), _param(p_off), _param(off_level),
         _param(idle_frac), block_b=block_b, block_t=block_t, t_total=t,

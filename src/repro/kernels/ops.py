@@ -84,7 +84,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     qg = q.reshape(b, g, r, dh)
     kt = _pad_to(k.transpose(0, 2, 1, 3), 2, bkv)      # [B,G,W*,Dh]
     vt = _pad_to(v.transpose(0, 2, 1, 3), 2, bkv)
-    vm = _pad_to(valid.astype(jnp.int8), 1, bkv)       # [B,W*]
+    vm = _pad_to(valid.astype(jnp.int8), 1, bkv)[:, None]   # [B,1,W*]
 
     out = decode_attention_bgrd(qg, kt, vt, vm, bkv=bkv,
                                 interpret=_auto_interpret(interpret))
@@ -116,7 +116,7 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array,
     nc = s // chunk
 
     xk = x.transpose(0, 2, 1, 3).reshape(bsz, nh, nc, chunk, p)
-    dtk = dt.transpose(0, 2, 1).reshape(bsz, nh, nc, chunk)
+    dtk = dt.transpose(0, 2, 1).reshape(bsz, nh, nc, chunk, 1)
     bk = b.transpose(0, 2, 1, 3).reshape(bsz, g, nc, chunk, n)
     ck = c.transpose(0, 2, 1, 3).reshape(bsz, g, nc, chunk, n)
     h0k = jnp.zeros((bsz, nh, n, p), jnp.float32) if h0 is None \

@@ -310,10 +310,53 @@ def soft_scan_ref(prices: jax.Array, p_on: jax.Array, p_off: jax.Array,
     return FleetScanOut(*acc)
 
 
+def _row(v) -> jax.Array:
+    """[n] (or [1, n]) -> [1, n]. The per-hour dispatch math runs on rows:
+    one hour as the Pallas kernels read it (one sublane, n lanes), so the
+    oracle and the kernels evaluate the same ops on the same shapes."""
+    return jnp.reshape(jnp.asarray(v), (1, -1))
+
+
+def _gather_lanes(x: jax.Array, idx: jax.Array, onehot: bool) -> jax.Array:
+    """``x[0, idx[0]]`` for rows x [1, n] of finite values and int idx
+    [1, m]. ``onehot`` (the Pallas kernels: Mosaic lowers no 1-D gather)
+    computes it as a one-hot matmul: every output sums exactly one
+    nonzero product (the rest are x_k * 0), so it equals the plain gather
+    bit for bit in any summation order; HIGHEST keeps f32 operands
+    unrounded on the MXU. The matmul costs O(n m) per call, so the XLA
+    paths take the O(m) gather."""
+    if not onehot:
+        return jnp.take_along_axis(x, idx, axis=-1)
+    k = jax.lax.broadcasted_iota(jnp.int32, (x.shape[-1], idx.shape[-1]), 0)
+    onehot = (idx == k).astype(x.dtype)
+    return jnp.dot(x, onehot, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=x.dtype)
+
+
+def doubling_prefix(x: jax.Array, op=jnp.add, fill=0, axis: int = -1
+                    ) -> jax.Array:
+    """Inclusive prefix ``op`` (add or maximum) along ``axis`` by
+    log-depth doubling, ``fill`` shifted in past the edge: a fixed
+    association of plain elementwise ops, the same on every backend.
+    Mosaic lowers no `cumsum` or `cummax`, so the kernels scan with
+    this."""
+    axis = axis % x.ndim
+    n = x.shape[axis]
+    d = 1
+    while d < n:
+        pad = jnp.full(x.shape[:axis] + (d,) + x.shape[axis + 1:], fill,
+                       x.dtype)
+        x = op(x, jnp.concatenate(
+            [pad, jax.lax.slice_in_dim(x, 0, n - d, axis=axis)], axis=axis))
+        d *= 2
+    return x
+
+
 def dispatch_alloc_hour(prev: jax.Array, dwell: jax.Array,
                         avail: jax.Array, order: jax.Array,
-                        rank: jax.Array, demand,
-                        *, min_dwell: int) -> tuple[jax.Array, jax.Array]:
+                        rank: jax.Array, demand, *, min_dwell: int,
+                        onehot: bool = False
+                        ) -> tuple[jax.Array, jax.Array]:
     """One hour of feasible cross-site dispatch (greedy water-fill).
 
     Shared *verbatim* by `dispatch_ref` and the Pallas kernel
@@ -335,29 +378,38 @@ def dispatch_alloc_hour(prev: jax.Array, dwell: jax.Array,
     (`repro.dispatch.segment_rank`): keys depend only on prices and the
     migration premium, never on the running state. The greedy fill is
     then sort-free — gather the widths into price order, one exclusive
-    cumsum, gather each segment's cheaper-mass back, and take
-    ``clip(demand - cheaper_mass, 0, width)`` — O(S) work per hour.
+    prefix sum, gather each segment's cheaper-mass back, and take
+    ``clip(demand - cheaper_mass, 0, width)``. The prefix sum is a fixed
+    doubling (`doubling_prefix`) and the gathers exact (`_gather_lanes`):
+    one-hot matmuls with ``onehot``, which the Pallas kernels pass
+    because the TPU kernel compiler lowers no 1-D gather, plain gathers
+    otherwise. Both forms give the same bits.
 
-    prev/dwell/avail: [S]; order/rank: [3S] int32; demand: scalar MW.
-    Returns ``(alloc [S], dwell' [S])``. Capacity loss breaks a dwell
-    lock (physics beats contract): locked width is capped at ``avail``.
+    prev/dwell/avail: [S] or [1, S]; order/rank: [3S] or [1, 3S] int;
+    demand: MW, scalar or [1, 1]. Returns ``(alloc, dwell')`` shaped
+    like ``prev``.
+    Capacity loss breaks a dwell lock (physics beats contract): locked
+    width is capped at ``avail``.
     """
-    s = prev.shape[0]
+    shape = jnp.shape(prev)
+    prev, dwell, avail, order, rank, demand = map(
+        _row, (prev, dwell, avail, order, rank, demand))
+    s = prev.shape[-1]
     held = jnp.minimum(prev, avail)
     if min_dwell > 0:
         locked = jnp.where(dwell > 0.0, held, 0.0)
     else:
         locked = jnp.zeros_like(held)
-    widths = jnp.concatenate([locked, held - locked, avail - held])
-    sorted_w = jnp.take(widths, order)
-    excl = jnp.cumsum(sorted_w) - sorted_w
-    before = jnp.take(excl, rank)        # MW at strictly cheaper segments
+    widths = jnp.concatenate([locked, held - locked, avail - held], axis=-1)
+    sorted_w = _gather_lanes(widths, order, onehot)
+    excl = doubling_prefix(sorted_w) - sorted_w
+    before = _gather_lanes(excl, rank, onehot)   # MW at cheaper segments
     fill = jnp.clip(demand - before, 0.0, widths)
-    alloc = fill[:s] + fill[s:2 * s] + fill[2 * s:]
+    alloc = fill[:, :s] + fill[:, s:2 * s] + fill[:, 2 * s:]
     if min_dwell > 0:
         dwell = jnp.where(alloc > prev + DWELL_EVENT_MW, float(min_dwell),
                           jnp.maximum(dwell - 1.0, 0.0))
-    return alloc, dwell
+    return alloc.reshape(shape), dwell.reshape(shape)
 
 
 def dispatch_ref(avail: jax.Array, order: jax.Array, rank: jax.Array,
@@ -434,10 +486,22 @@ def soft_water_level(keys: jax.Array, widths: jax.Array, demand,
     "everything full" instead of emitting huge levels; callers
     renormalise the fill mass, so the clip never distorts feasible
     hours, where the correction is O(bracket / 2^n_bisect).
+
+    keys/widths: [n] or [1, n]; the level comes back shaped like
+    ``demand``.
     """
+    shape = jnp.shape(demand)
+    keys, widths, demand, lam0 = map(_row, (keys, widths, demand, lam0))
     lam_hat = _bisect_level(keys, widths, demand, lam0, inv_tau,
                             n_bisect=n_bisect)
-    return soft_water_level_fixed(keys, widths, demand, lam_hat, inv_tau)
+    return soft_water_level_fixed(keys, widths, demand, lam_hat,
+                                  inv_tau).reshape(shape)
+
+
+def _lane_sum(x: jax.Array) -> jax.Array:
+    """Sum of a row [1, n] as [1, 1]: per-hour scalars stay vectors, the
+    form the kernels hold them in."""
+    return jnp.sum(x, axis=-1, keepdims=True)
 
 
 def _bisect_level(keys: jax.Array, widths: jax.Array, demand, lam0,
@@ -446,11 +510,12 @@ def _bisect_level(keys: jax.Array, widths: jax.Array, demand, lam0,
     bisection from the hard-level bracket, returned under
     ``stop_gradient``. Saved as a per-hour residual by the fused
     dispatch VJP (`repro.kernels.soft_dispatch`) so the backward pass
-    never re-runs the solver."""
+    never re-runs the solver. keys/widths: [1, n] rows; demand and
+    lam0: [1, 1]."""
     span = _WL_SIGMA_SPAN / inv_tau
 
     def f(lam):
-        return jnp.sum(widths * jax.nn.sigmoid((lam - keys) * inv_tau))
+        return _lane_sum(widths * jax.nn.sigmoid((lam - keys) * inv_tau))
 
     def bisect(_, lo_hi):
         lo, hi = lo_hi
@@ -470,22 +535,27 @@ def soft_water_level_fixed(keys: jax.Array, widths: jax.Array, demand,
     gradient flow of the water level lives here — given the same
     ``lam_hat`` the composition is bitwise-identical to the original
     fused form, which is what lets the custom VJP replay this half from
-    a saved residual."""
+    a saved residual. Shapes as in `_bisect_level`."""
     lam_hat = jax.lax.stop_gradient(lam_hat)
     span = _WL_SIGMA_SPAN / inv_tau
-    sig = jax.nn.sigmoid((lam_hat - keys) * inv_tau)
+    z = (lam_hat - keys) * inv_tau
+    sig = jax.nn.sigmoid(z)
+    # f' uses sigmoid(-z), not 1 - sig: once the marginal segment's sig
+    # rounds to 1 (the level sits ~17 tau above its key when its width
+    # exceeds the demand by one ulp) 1 - sig is 0, f' hits the floor and
+    # the step jumps to -span, emptying every segment
     denom = jnp.maximum(
-        jax.lax.stop_gradient(jnp.sum(widths * sig * (1.0 - sig))
+        jax.lax.stop_gradient(_lane_sum(widths * sig * jax.nn.sigmoid(-z))
                               * inv_tau), _WL_TINY)
-    step = (demand - jnp.sum(widths * sig)) / denom
+    step = (demand - _lane_sum(widths * sig)) / denom
     return lam_hat + jnp.clip(step, -span, span)
 
 
 def soft_dispatch_hour(prev: jax.Array, dwell: jax.Array,
                        avail: jax.Array, keys: jax.Array,
                        order: jax.Array, demand, *, inv_tau, inv_tau_mw,
-                       min_dwell: int,
-                       n_bisect: int = 30) -> tuple[jax.Array, jax.Array]:
+                       min_dwell: int, n_bisect: int = 30,
+                       onehot: bool = False) -> tuple[jax.Array, jax.Array]:
     """One hour of the temperature-``tau`` softmin water-fill — the
     relaxation of `dispatch_alloc_hour`.
 
@@ -515,25 +585,27 @@ def soft_dispatch_hour(prev: jax.Array, dwell: jax.Array,
     the sorted widths' cumulative mass past the demand). The fill is
     renormalised to sum exactly to the demand (scale -> 1 as tau -> 0),
     which also zeroes allocation on zero-demand padded hours.
-    prev/dwell/avail: [S]; keys: [3S]; order: [3S] int32.
-    Returns ``(alloc [S], dwell' [S])``.
+    prev/dwell/avail: [S] or [1, S]; keys/order: [3S] or [1, 3S].
+    ``onehot`` picks the gather form as in `dispatch_alloc_hour`.
+    Returns ``(alloc, dwell')`` shaped like ``prev``.
     """
     alloc, dwell, _ = soft_dispatch_hour_parts(
         prev, dwell, avail, keys, order, demand, inv_tau=inv_tau,
-        inv_tau_mw=inv_tau_mw, min_dwell=min_dwell, n_bisect=n_bisect)
+        inv_tau_mw=inv_tau_mw, min_dwell=min_dwell, n_bisect=n_bisect,
+        onehot=onehot)
     return alloc, dwell
 
 
 def _hour_widths(prev: jax.Array, dwell: jax.Array, avail: jax.Array, *,
                  inv_tau, min_dwell: int) -> jax.Array:
-    """[3S] locked / retain / fresh segment widths of one hour."""
+    """[..., 3S] locked / retain / fresh segment widths of one hour."""
     held = jnp.minimum(prev, avail)
     if min_dwell > 0:
         inv_tau_cnt = inv_tau / _DWELL_CNT_SCALE
         locked = jax.nn.sigmoid((dwell - 0.5) * inv_tau_cnt) * held
     else:
         locked = jnp.zeros_like(held)
-    return jnp.concatenate([locked, held - locked, avail - held])
+    return jnp.concatenate([locked, held - locked, avail - held], axis=-1)
 
 
 def soft_dispatch_hour_fixed(prev: jax.Array, dwell: jax.Array,
@@ -552,17 +624,21 @@ def soft_dispatch_hour_fixed(prev: jax.Array, dwell: jax.Array,
     forward saves ``lam_hat`` per hour, and the backward is the
     `jax.vjp` transpose of *this* function — the same linear map native
     autodiff would build, just replayed from slim residuals
-    (`soft_dispatch_hour_grad`).
+    (`soft_dispatch_hour_grad`). Runs on rows like
+    `dispatch_alloc_hour`; outputs are shaped like ``prev``.
     """
-    s = prev.shape[0]
+    shape = jnp.shape(prev)
+    prev, dwell, avail, keys, demand, lam_hat = map(
+        _row, (prev, dwell, avail, keys, demand, lam_hat))
+    s = prev.shape[-1]
     widths = _hour_widths(prev, dwell, avail, inv_tau=inv_tau,
                           min_dwell=min_dwell)
     lam = soft_water_level_fixed(keys, widths, demand, lam_hat, inv_tau)
 
     fill = widths * jax.nn.sigmoid((lam - keys) * inv_tau)
-    fill = fill * (demand / jnp.maximum(jnp.sum(fill),
+    fill = fill * (demand / jnp.maximum(_lane_sum(fill),
                                         1e-9 * demand + _WL_TINY))
-    alloc = fill[:s] + fill[s:2 * s] + fill[2 * s:]
+    alloc = fill[:, :s] + fill[:, s:2 * s] + fill[:, 2 * s:]
     if min_dwell > 0:
         inv_tau_cnt = inv_tau / _DWELL_CNT_SCALE
         moved_in = jax.nn.sigmoid((alloc - prev - DWELL_EVENT_MW)
@@ -570,33 +646,40 @@ def soft_dispatch_hour_fixed(prev: jax.Array, dwell: jax.Array,
         count_down = jax.nn.softplus((dwell - 1.0) * inv_tau_cnt) \
             / inv_tau_cnt
         dwell = moved_in * min_dwell + (1.0 - moved_in) * count_down
-    return alloc, dwell
+    return alloc.reshape(shape), dwell.reshape(shape)
 
 
 def soft_dispatch_hour_parts(prev: jax.Array, dwell: jax.Array,
                              avail: jax.Array, keys: jax.Array,
                              order: jax.Array, demand, *, inv_tau,
                              inv_tau_mw, min_dwell: int,
-                             n_bisect: int = 30
+                             n_bisect: int = 30, onehot: bool = False
                              ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """`soft_dispatch_hour` that also returns the bisected level
     ``lam_hat`` — the one extra per-hour residual the fused VJP saves.
-    ``(alloc, dwell')`` are bitwise those of `soft_dispatch_hour`."""
-    s = prev.shape[0]
+    ``(alloc, dwell')`` are bitwise those of `soft_dispatch_hour`; the
+    sort walk uses the exact gathers (``onehot`` as there) and the
+    doubling prefix sum of `dispatch_alloc_hour`."""
+    shape, lam_shape = jnp.shape(prev), jnp.shape(demand)
+    prev, dwell, avail, keys, order, demand = map(
+        _row, (prev, dwell, avail, keys, order, demand))
+    s = prev.shape[-1]
     widths = _hour_widths(prev, dwell, avail, inv_tau=inv_tau,
                           min_dwell=min_dwell)
-    sorted_w = jnp.take(widths, order)
-    cums = jnp.cumsum(sorted_w)
-    marginal = jnp.minimum(jnp.sum((cums < demand).astype(jnp.int32)),
+    cums = doubling_prefix(_gather_lanes(widths, order, onehot))
+    marginal = jnp.minimum(_lane_sum((cums < demand).astype(jnp.int32)),
                            3 * s - 1)
+    sorted_keys = _gather_lanes(keys, order, onehot)
+    pos = jax.lax.broadcasted_iota(jnp.int32, sorted_keys.shape, 1)
     lam0 = jax.lax.stop_gradient(
-        jnp.take(jnp.take(keys, order), marginal))
+        _lane_sum(jnp.where(pos == marginal, sorted_keys, 0.0)))
     lam_hat = _bisect_level(keys, widths, demand, lam0, inv_tau,
                             n_bisect=n_bisect)
     alloc, dwell = soft_dispatch_hour_fixed(
         prev, dwell, avail, keys, demand, lam_hat, inv_tau, inv_tau_mw,
         min_dwell=min_dwell)
-    return alloc, dwell, lam_hat
+    return (alloc.reshape(shape), dwell.reshape(shape),
+            lam_hat.reshape(lam_shape))
 
 
 def soft_dispatch_hour_grad(prev: jax.Array, dwell: jax.Array,

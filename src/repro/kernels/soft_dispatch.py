@@ -36,7 +36,10 @@ FD gradient checks are exact); on TPU a Pallas kernel with grid =
 the (prev alloc, dwell) carry in VMEM scratch — zero HBM round-trips
 for state. T-padding needs no masking: padded hours carry zero demand,
 and the renormalised fill is exactly zero there. Validated in
-interpret mode against `soft_dispatch_ref`.
+interpret mode against `soft_dispatch_ref`. The per-hour one-hot gathers
+hold [3S, 3S] operands in VMEM: at a v5e's default 16 MiB of scoped VMEM
+the kernels compile up to S = 256 sites and are refused at S = 512
+(about 41 MiB asked).
 """
 
 from __future__ import annotations
@@ -50,14 +53,18 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.dispatch_scan import time_block
 from repro.kernels.ref import (soft_dispatch_hour, soft_dispatch_hour_grad,
                                soft_dispatch_hour_parts, soft_dispatch_ref)
 
 
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)   # whole (1,) scalar operand
+
+
 def _soft_dispatch_kernel(a_ref, keys_ref, order_ref, d_ref,  # time-major
-                          itau_ref, itaumw_ref,               # (1,) scalars
+                          itau_ref, itaumw_ref,               # (1,) SMEM
                           out_ref,                            # [block_t, S]
-                          prev_scr, dwell_scr,                # [S] VMEM carry
+                          prev_scr, dwell_scr,                # [1, S] carry
                           *, block_t: int, min_dwell: int, n_bisect: int):
     ti = pl.program_id(0)
 
@@ -70,12 +77,13 @@ def _soft_dispatch_kernel(a_ref, keys_ref, order_ref, d_ref,  # time-major
     inv_tau_mw = itaumw_ref[0]
 
     def hour(h, carry):
+        row = pl.ds(h, 1)                             # one hour, [1, n]
         alloc, dwell = soft_dispatch_hour(
-            prev_scr[...], dwell_scr[...], a_ref[h, :], keys_ref[h, :],
-            order_ref[h, :], d_ref[h], inv_tau=inv_tau,
+            prev_scr[...], dwell_scr[...], a_ref[row, :], keys_ref[row, :],
+            order_ref[row, :], d_ref[row, :], inv_tau=inv_tau,
             inv_tau_mw=inv_tau_mw, min_dwell=min_dwell,
-            n_bisect=n_bisect)
-        out_ref[h, :] = alloc
+            n_bisect=n_bisect, onehot=True)
+        out_ref[row, :] = alloc
         prev_scr[...] = alloc
         dwell_scr[...] = dwell
         return carry
@@ -92,8 +100,9 @@ def _soft_dispatch_padded(a_tm: jax.Array, keys: jax.Array,
                           interpret: bool) -> jax.Array:
     """Core pallas_call over padded, time-major inputs.
 
-    a_tm: [T*, S]; keys/order: [T*, 3S]; demand: [T*]; itau/itaumw:
-    (1,) (T* a block_t multiple). Returns the allocation [T*, S].
+    a_tm: [T*, S]; keys/order: [T*, 3S]; demand: [T*, 1] (a column:
+    each hour reads a [1, 1] vector); itau/itaumw: (1,) in SMEM (T* a
+    block_t multiple). Returns the allocation [T*, S].
     """
     t_pad, s = a_tm.shape
     nt = t_pad // block_t
@@ -107,14 +116,14 @@ def _soft_dispatch_padded(a_tm: jax.Array, keys: jax.Array,
             pl.BlockSpec((block_t, s), lambda ti: (ti, 0)),
             pl.BlockSpec((block_t, 3 * s), lambda ti: (ti, 0)),
             pl.BlockSpec((block_t, 3 * s), lambda ti: (ti, 0)),
-            pl.BlockSpec((block_t,), lambda ti: (ti,)),
-            pl.BlockSpec((1,), lambda ti: (0,)),
-            pl.BlockSpec((1,), lambda ti: (0,)),
+            pl.BlockSpec((block_t, 1), lambda ti: (ti, 0)),
+            _SMEM,
+            _SMEM,
         ],
         out_specs=pl.BlockSpec((block_t, s), lambda ti: (ti, 0)),
         out_shape=jax.ShapeDtypeStruct((t_pad, s), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((s,), jnp.float32),
-                        pltpu.VMEM((s,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, s), jnp.float32),
+                        pltpu.VMEM((1, s), jnp.float32)],
         interpret=interpret,
     )(a_tm, keys, order, demand, itau, itaumw)
 
@@ -136,13 +145,13 @@ def soft_dispatch_pallas(avail: jax.Array, keys: jax.Array,
     it (asserted in `tests/test_soft_dispatch.py`)."""
     a = jnp.asarray(avail, jnp.float32)
     s, t = a.shape
-    block_t = max(min(block_t, t), 1)
+    block_t = time_block(t, block_t)
     pad_t = (-t) % block_t
 
     a_tm = jnp.pad(a.T, ((0, pad_t), (0, 0)))        # [T*, S] time-major
     keys_p = jnp.pad(jnp.asarray(keys, jnp.float32), ((0, pad_t), (0, 0)))
     order_p = jnp.pad(jnp.asarray(order, jnp.int32), ((0, pad_t), (0, 0)))
-    d_p = jnp.pad(jnp.asarray(demand, jnp.float32), (0, pad_t))
+    d_p = jnp.pad(jnp.asarray(demand, jnp.float32), (0, pad_t))[:, None]
     itau = (1.0 / jnp.asarray(tau, jnp.float32)).reshape(1)
     itaumw = itau / jnp.float32(mw_scale)
     out = _soft_dispatch_padded(a_tm, keys_p, order_p, d_p, itau, itaumw,
@@ -224,9 +233,9 @@ def _xla_fused_bwd(a, keys, demand, inv_tau, inv_tau_mw, alloc, dwin,
 
 
 def _fused_fwd_kernel(a_ref, keys_ref, order_ref, d_ref,      # time-major
-                      itau_ref, itaumw_ref,                   # (1,) scalars
+                      itau_ref, itaumw_ref,                   # (1,) SMEM
                       out_ref, dwin_ref, lam_ref,             # residuals out
-                      prev_scr, dwell_scr,                    # [S] VMEM carry
+                      prev_scr, dwell_scr,                    # [1, S] carry
                       *, block_t: int, min_dwell: int, n_bisect: int):
     ti = pl.program_id(0)
 
@@ -239,14 +248,15 @@ def _fused_fwd_kernel(a_ref, keys_ref, order_ref, d_ref,      # time-major
     inv_tau_mw = itaumw_ref[0]
 
     def hour(h, carry):
-        dwin_ref[h, :] = dwell_scr[...]              # entering dwell
+        row = pl.ds(h, 1)
+        dwin_ref[row, :] = dwell_scr[...]            # entering dwell
         alloc, dwell, lam_hat = soft_dispatch_hour_parts(
-            prev_scr[...], dwell_scr[...], a_ref[h, :], keys_ref[h, :],
-            order_ref[h, :], d_ref[h], inv_tau=inv_tau,
+            prev_scr[...], dwell_scr[...], a_ref[row, :], keys_ref[row, :],
+            order_ref[row, :], d_ref[row, :], inv_tau=inv_tau,
             inv_tau_mw=inv_tau_mw, min_dwell=min_dwell,
-            n_bisect=n_bisect)
-        out_ref[h, :] = alloc
-        lam_ref[h] = lam_hat
+            n_bisect=n_bisect, onehot=True)
+        out_ref[row, :] = alloc
+        lam_ref[row, :] = lam_hat
         prev_scr[...] = alloc
         dwell_scr[...] = dwell
         return carry
@@ -260,7 +270,8 @@ def _pallas_fused_fwd(a_tm, keys, order, demand, itau, itaumw, *,
                       block_t: int, min_dwell: int, n_bisect: int,
                       interpret: bool):
     """pallas_call of the residual-emitting forward over padded,
-    time-major inputs (same layout as `_soft_dispatch_padded`)."""
+    time-major inputs (same layout as `_soft_dispatch_padded`; the level
+    residual is a [T*, 1] column like the demand)."""
     t_pad, s = a_tm.shape
     nt = t_pad // block_t
 
@@ -273,18 +284,18 @@ def _pallas_fused_fwd(a_tm, keys, order, demand, itau, itaumw, *,
             pl.BlockSpec((block_t, s), lambda ti: (ti, 0)),
             pl.BlockSpec((block_t, 3 * s), lambda ti: (ti, 0)),
             pl.BlockSpec((block_t, 3 * s), lambda ti: (ti, 0)),
-            pl.BlockSpec((block_t,), lambda ti: (ti,)),
-            pl.BlockSpec((1,), lambda ti: (0,)),
-            pl.BlockSpec((1,), lambda ti: (0,)),
+            pl.BlockSpec((block_t, 1), lambda ti: (ti, 0)),
+            _SMEM,
+            _SMEM,
         ],
         out_specs=[pl.BlockSpec((block_t, s), lambda ti: (ti, 0)),
                    pl.BlockSpec((block_t, s), lambda ti: (ti, 0)),
-                   pl.BlockSpec((block_t,), lambda ti: (ti,))],
+                   pl.BlockSpec((block_t, 1), lambda ti: (ti, 0))],
         out_shape=[jax.ShapeDtypeStruct((t_pad, s), jnp.float32),
                    jax.ShapeDtypeStruct((t_pad, s), jnp.float32),
-                   jax.ShapeDtypeStruct((t_pad,), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((s,), jnp.float32),
-                        pltpu.VMEM((s,), jnp.float32)],
+                   jax.ShapeDtypeStruct((t_pad, 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, s), jnp.float32),
+                        pltpu.VMEM((1, s), jnp.float32)],
         interpret=interpret,
     )(a_tm, keys, order, demand, itau, itaumw)
 
@@ -297,8 +308,8 @@ def _fused_bwd_kernel(prev_ref, dwin_ref, lam_ref, a_ref, keys_ref,
     """One reversed time block of the backward: the index maps walk
     blocks last-to-first, hours run block_t-1 .. 0 inside, and the
     (prev, dwell) adjoints cross block boundaries in VMEM scratch. The
-    two tau-chain accumulators ride along in scratch and are emitted
-    once, from the final (earliest-time) block."""
+    two tau-chain accumulators ride along in a [2, 1] scratch and are
+    emitted once, from the final (earliest-time) block."""
     ti = pl.program_id(0)
 
     @pl.when(ti == 0)
@@ -311,20 +322,20 @@ def _fused_bwd_kernel(prev_ref, dwin_ref, lam_ref, a_ref, keys_ref,
     inv_tau_mw = itaumw_ref[0]
 
     def hour(i, carry):
-        h = block_t - 1 - i
+        row = pl.ds(block_t - 1 - i, 1)
         d_p, d_dw, d_av, d_ke, d_de, d_it, d_itm = \
             soft_dispatch_hour_grad(
-                prev_ref[h, :], dwin_ref[h, :], a_ref[h, :],
-                keys_ref[h, :], d_ref[h], lam_ref[h], inv_tau,
-                inv_tau_mw, g_ref[h, :] + uprev_scr[...],
+                prev_ref[row, :], dwin_ref[row, :], a_ref[row, :],
+                keys_ref[row, :], d_ref[row, :], lam_ref[row, :], inv_tau,
+                inv_tau_mw, g_ref[row, :] + uprev_scr[...],
                 udwell_scr[...], min_dwell=min_dwell)
-        dav_ref[h, :] = d_av
-        dke_ref[h, :] = d_ke
-        dde_ref[h] = d_de
+        dav_ref[row, :] = d_av
+        dke_ref[row, :] = d_ke
+        dde_ref[row, :] = d_de
         uprev_scr[...] = d_p
         udwell_scr[...] = d_dw
-        acc_scr[0] = acc_scr[0] + d_it
-        acc_scr[1] = acc_scr[1] + d_itm
+        acc_scr[0:1, :] += jnp.reshape(d_it, (1, 1))
+        acc_scr[1:2, :] += jnp.reshape(d_itm, (1, 1))
         return carry
 
     jax.lax.fori_loop(0, block_t, hour, 0)
@@ -342,7 +353,6 @@ def _pallas_fused_bwd(prev_tm, dwin_tm, lam, a_tm, keys, demand, g_tm,
     t_pad, s = a_tm.shape
     nt = t_pad // block_t
     rev2 = lambda ti: (nt - 1 - ti, 0)          # noqa: E731
-    rev1 = lambda ti: (nt - 1 - ti,)            # noqa: E731
 
     kernel = functools.partial(_fused_bwd_kernel, block_t=block_t,
                                min_dwell=min_dwell, n_t_blocks=nt)
@@ -352,25 +362,25 @@ def _pallas_fused_bwd(prev_tm, dwin_tm, lam, a_tm, keys, demand, g_tm,
         in_specs=[
             pl.BlockSpec((block_t, s), rev2),
             pl.BlockSpec((block_t, s), rev2),
-            pl.BlockSpec((block_t,), rev1),
+            pl.BlockSpec((block_t, 1), rev2),
             pl.BlockSpec((block_t, s), rev2),
             pl.BlockSpec((block_t, 3 * s), rev2),
-            pl.BlockSpec((block_t,), rev1),
+            pl.BlockSpec((block_t, 1), rev2),
             pl.BlockSpec((block_t, s), rev2),
-            pl.BlockSpec((1,), lambda ti: (0,)),
-            pl.BlockSpec((1,), lambda ti: (0,)),
+            _SMEM,
+            _SMEM,
         ],
         out_specs=[pl.BlockSpec((block_t, s), rev2),
                    pl.BlockSpec((block_t, 3 * s), rev2),
-                   pl.BlockSpec((block_t,), rev1),
-                   pl.BlockSpec((2,), lambda ti: (0,))],
+                   pl.BlockSpec((block_t, 1), rev2),
+                   pl.BlockSpec((2, 1), lambda ti: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct((t_pad, s), jnp.float32),
                    jax.ShapeDtypeStruct((t_pad, 3 * s), jnp.float32),
-                   jax.ShapeDtypeStruct((t_pad,), jnp.float32),
-                   jax.ShapeDtypeStruct((2,), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((s,), jnp.float32),
-                        pltpu.VMEM((s,), jnp.float32),
-                        pltpu.VMEM((2,), jnp.float32)],
+                   jax.ShapeDtypeStruct((t_pad, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((2, 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, s), jnp.float32),
+                        pltpu.VMEM((1, s), jnp.float32),
+                        pltpu.VMEM((2, 1), jnp.float32)],
         interpret=interpret,
     )(prev_tm, dwin_tm, lam, a_tm, keys, demand, g_tm, itau, itaumw)
 
@@ -406,17 +416,17 @@ def _fused_primal(avail, keys, order, demand, tau, min_dwell, mw_scale,
         return _xla_fused_fwd(avail, keys, jnp.asarray(order, jnp.int32),
                               demand, inv_tau, inv_tau_mw,
                               min_dwell=min_dwell, n_bisect=n_bisect)
-    bt = max(min(block_t, t), 1)
+    bt = time_block(t, block_t)
     pad_t = (-t) % bt
     alloc_tm, dwin_tm, lam = _pallas_fused_fwd(
         _pallas_pad(avail.T, pad_t), _pallas_pad(keys, pad_t),
         jnp.pad(jnp.asarray(order, jnp.int32), ((0, pad_t), (0, 0))),
-        _pallas_pad(demand, pad_t),
+        _pallas_pad(demand[:, None], pad_t),
         jnp.asarray(inv_tau, jnp.float32).reshape(1),
         jnp.asarray(inv_tau_mw, jnp.float32).reshape(1),
         block_t=bt, min_dwell=min_dwell, n_bisect=n_bisect,
         interpret=_auto_interpret(interpret))
-    return alloc_tm[:t].T, dwin_tm[:t].T, lam[:t]
+    return alloc_tm[:t].T, dwin_tm[:t].T, lam[:t, 0]
 
 
 def _fused_fwd(avail, keys, order, demand, tau, min_dwell, mw_scale,
@@ -439,14 +449,15 @@ def _fused_bwd(min_dwell, mw_scale, n_bisect, block_t, use_pallas,
             g, min_dwell=min_dwell)
     else:
         s, t = avail.shape
-        bt = max(min(block_t, t), 1)
+        bt = time_block(t, block_t)
         pad_t = (-t) % bt
         prev = jnp.concatenate([jnp.zeros_like(alloc[:, :1]),
                                 alloc[:, :-1]], axis=1)
         d_av_tm, d_ke, d_de, sums = _pallas_fused_bwd(
             _pallas_pad(prev.T, pad_t), _pallas_pad(dwin.T, pad_t),
-            _pallas_pad(lam, pad_t), _pallas_pad(avail.T, pad_t, 1.0),
-            _pallas_pad(keys, pad_t), _pallas_pad(demand, pad_t, 1.0),
+            _pallas_pad(lam[:, None], pad_t),
+            _pallas_pad(avail.T, pad_t, 1.0), _pallas_pad(keys, pad_t),
+            _pallas_pad(demand[:, None], pad_t, 1.0),
             _pallas_pad(g.T, pad_t),
             jnp.asarray(inv_tau, jnp.float32).reshape(1),
             jnp.asarray(inv_tau_mw, jnp.float32).reshape(1),
@@ -454,8 +465,8 @@ def _fused_bwd(min_dwell, mw_scale, n_bisect, block_t, use_pallas,
             interpret=_auto_interpret(interpret))
         d_av = d_av_tm[:t].T.astype(avail.dtype)
         d_ke = d_ke[:t].astype(keys.dtype)
-        d_de = d_de[:t].astype(demand.dtype)
-        acc_it, acc_itm = sums[0], sums[1]
+        d_de = d_de[:t, 0].astype(demand.dtype)
+        acc_it, acc_itm = sums[0, 0], sums[1, 0]
     # tau -> (inv_tau, inv_tau_mw) chain (see soft_dispatch_grad_ref)
     d_tau = (-(inv_tau ** 2) * acc_it
              - inv_tau * inv_tau_mw * acc_itm).astype(tau.dtype)

@@ -198,19 +198,19 @@ def _fwd_kernel(p_ref, pon_ref, poff_ref, itau_ref,
     tloc = jax.lax.broadcasted_iota(jnp.int32, p.shape, 0)
     valid = (ti * block_t + tloc) < t_total
 
-    _, _, alpha, beta = soft_gates(p, pon[None, :], poff[None, :], inv_tau)
+    _, _, alpha, beta = soft_gates(p, pon, poff, inv_tau)
     alpha = jnp.where(valid, alpha, 1.0)               # identity padding
     beta = jnp.where(valid, beta, 0.0)
 
-    carry = state_scr[...]                             # [bb]
-    carr_ref[...] = carry[None, :]                     # entering state
+    carry = state_scr[...]                             # [1, bb]
+    carr_ref[...] = carry                              # entering state
     # fold the entering state into acc_0 (static-slice concat, not a
     # scatter — lowers cleanly on the VPU)
-    beta = jnp.concatenate([beta[:1] + alpha[:1] * carry[None, :],
+    beta = jnp.concatenate([beta[:1] + alpha[:1] * carry,
                             beta[1:]], axis=0)
     s = _prefix_linear(alpha, beta)
     s_ref[...] = s
-    state_scr[...] = s[-1]
+    state_scr[...] = s[block_t - 1:]
 
 
 def _bwd_kernel(p_ref, g_ref, pon_ref, poff_ref, itau_ref, carr_ref,
@@ -235,38 +235,36 @@ def _bwd_kernel(p_ref, g_ref, pon_ref, poff_ref, itau_ref, carr_ref,
     tloc = jax.lax.broadcasted_iota(jnp.int32, p.shape, 0)
     valid = (bi * block_t + tloc) < t_total
 
-    a_gate, f_gate, alpha, beta = soft_gates(p, pon[None, :],
-                                             poff[None, :], inv_tau)
+    a_gate, f_gate, alpha, beta = soft_gates(p, pon, poff, inv_tau)
     alpha = jnp.where(valid, alpha, 1.0)
     beta = jnp.where(valid, beta, 0.0)
     g = jnp.where(valid, g, 0.0)
 
     # recompute the block's states from the saved entering carry
-    carry = carr_ref[0, :]                         # [bb]
-    beta_f = jnp.concatenate([beta[:1] + alpha[:1] * carry[None, :],
+    carry = carr_ref[...]                          # [1, bb]
+    beta_f = jnp.concatenate([beta[:1] + alpha[:1] * carry,
                               beta[1:]], axis=0)
     s = _prefix_linear(alpha, beta_f)
-    s_prev = jnp.concatenate([carry[None, :], s[:-1]], axis=0)
+    s_prev = jnp.concatenate([carry, s[:-1]], axis=0)
 
     # adjoint within the block, seeded across the boundary by the later
     # block's first-hour adjoint: u_t = g_t + alpha_{t+1} u_{t+1}
     coeff = jnp.concatenate([alpha[1:],
                              jnp.zeros((1,) + alpha.shape[1:],
                                        alpha.dtype)], axis=0)
-    seed = (afirst_scr[...] * u_scr[...])[None, :]
+    seed = afirst_scr[...] * u_scr[...]            # [1, bb]
     g = jnp.concatenate([g[:-1], g[-1:] + seed], axis=0)
     u = _suffix_linear(coeff, g)
-    u_scr[...] = u[0]
-    afirst_scr[...] = alpha[0]
+    u_scr[...] = u[:1]
+    afirst_scr[...] = alpha[:1]
 
-    d_p, d_on, d_off, d_it = soft_gate_grad(p, s_prev, u, pon[None, :],
-                                            poff[None, :], inv_tau,
-                                            gates=(a_gate, f_gate))
+    d_p, d_on, d_off, d_it = soft_gate_grad(p, s_prev, u, pon, poff,
+                                            inv_tau, gates=(a_gate, f_gate))
     vf = valid.astype(jnp.float32)
     dp_ref[...] = d_p * vf
-    acc_scr[0, :] += jnp.sum(d_on * vf, axis=0)
-    acc_scr[1, :] += jnp.sum(d_off * vf, axis=0)
-    acc_scr[2, :] += jnp.sum(d_it * vf, axis=0)
+    acc_scr[...] += jnp.concatenate(
+        [jnp.sum(v * vf, axis=0, keepdims=True) for v in (d_on, d_off, d_it)],
+        axis=0)
 
     @pl.when(ti == n_t_blocks - 1)
     def _finish():
@@ -284,8 +282,10 @@ def _pallas_pad(p, p_on, p_off, block_b, block_t):
     pad_b = (-b) % block_b
     pad_t = (-t) % block_t
     p_tm = jnp.pad(p.astype(jnp.float32).T, ((0, pad_t), (0, pad_b)))
-    pon = jnp.pad(p_on.astype(jnp.float32), (0, pad_b))
-    poff = jnp.pad(p_off.astype(jnp.float32), (0, pad_b))
+    # params ride as [1, B*] rows: Mosaic tiles a 1-D operand differently
+    # from XLA
+    pon = jnp.pad(p_on.astype(jnp.float32), (0, pad_b))[None, :]
+    poff = jnp.pad(p_off.astype(jnp.float32), (0, pad_b))[None, :]
     return p_tm, pon, poff
 
 
@@ -302,19 +302,19 @@ def _pallas_fwd(p_tm, pon, poff, itau, *, block_b, block_t, t_total,
         grid=(nb, nt),
         in_specs=[
             pl.BlockSpec((block_t, block_b), lambda bi, ti: (ti, bi)),
-            pl.BlockSpec((block_b,), lambda bi, ti: (bi,)),
-            pl.BlockSpec((block_b,), lambda bi, ti: (bi,)),
+            pl.BlockSpec((1, block_b), lambda bi, ti: (0, bi)),
+            pl.BlockSpec((1, block_b), lambda bi, ti: (0, bi)),
             pl.BlockSpec((1,), lambda bi, ti: (0,)),
         ],
         out_specs=[
             pl.BlockSpec((block_t, block_b), lambda bi, ti: (ti, bi)),
-            pl.BlockSpec((1, block_b), lambda bi, ti: (ti, bi)),
+            pl.BlockSpec((None, 1, block_b), lambda bi, ti: (ti, 0, bi)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((t_pad, b_pad), jnp.float32),
-            jax.ShapeDtypeStruct((nt, b_pad), jnp.float32),
+            jax.ShapeDtypeStruct((nt, 1, b_pad), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((block_b,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, block_b), jnp.float32)],
         interpret=interpret,
     )(p_tm, pon, poff, itau)
 
@@ -334,10 +334,11 @@ def _pallas_bwd(p_tm, g_tm, pon, poff, itau, carr, *, block_b, block_t,
         in_specs=[
             pl.BlockSpec((block_t, block_b), rev),
             pl.BlockSpec((block_t, block_b), rev),
-            pl.BlockSpec((block_b,), lambda bi, ti: (bi,)),
-            pl.BlockSpec((block_b,), lambda bi, ti: (bi,)),
+            pl.BlockSpec((1, block_b), lambda bi, ti: (0, bi)),
+            pl.BlockSpec((1, block_b), lambda bi, ti: (0, bi)),
             pl.BlockSpec((1,), lambda bi, ti: (0,)),
-            pl.BlockSpec((1, block_b), rev),
+            pl.BlockSpec((None, 1, block_b),
+                         lambda bi, ti: (nt - 1 - ti, 0, bi)),
         ],
         out_specs=[
             pl.BlockSpec((block_t, block_b), rev),
@@ -347,8 +348,8 @@ def _pallas_bwd(p_tm, g_tm, pon, poff, itau, carr, *, block_b, block_t,
             jax.ShapeDtypeStruct((t_pad, b_pad), jnp.float32),
             jax.ShapeDtypeStruct((3, b_pad), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((block_b,), jnp.float32),
-                        pltpu.VMEM((block_b,), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((1, block_b), jnp.float32),
+                        pltpu.VMEM((1, block_b), jnp.float32),
                         pltpu.VMEM((3, block_b), jnp.float32)],
         interpret=interpret,
     )(p_tm, g_tm, pon, poff, itau, carr)
@@ -377,7 +378,7 @@ def _soft_state_fwd(p, p_on, p_off, tau, block_t, use_pallas, interpret):
                                  block_t=bt, t_total=t,
                                  interpret=interpret)
         s = s_tm[:t, :b].T.astype(p.dtype)
-        carries = carr[:, :b].T.astype(p.dtype)
+        carries = carr[:, 0, :b].T.astype(p.dtype)
     else:
         s, carries = _xla_fwd(p, p_on, p_off, inv_tau, block_t)
     # residuals: inputs + per-block entering states — O(B * T / block_t)
@@ -397,7 +398,7 @@ def _soft_state_bwd(block_t, use_pallas, interpret, res, g):
                        ((0, (-t) % bt), (0, (-b) % block_b)))
         itau = jnp.asarray(inv_tau, jnp.float32).reshape(1)
         carr = jnp.pad(carries.astype(jnp.float32).T,
-                       ((0, 0), (0, (-b) % block_b)))
+                       ((0, 0), (0, (-b) % block_b)))[:, None, :]
         dp_tm, sums = _pallas_bwd(p_tm, g_tm, pon, poff, itau, carr,
                                   block_b=block_b, block_t=bt, t_total=t,
                                   interpret=interpret)

@@ -37,6 +37,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ref import doubling_prefix
+
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, h0_ref,   # inputs
                 y_ref, hT_ref,                                # outputs
@@ -49,20 +51,27 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, h0_ref,   # inputs
         state_scr[...] = h0_ref[0, 0].astype(jnp.float32)     # [N, P]
 
     x = x_ref[0, 0, 0].astype(jnp.float32)                    # [L, P]
-    dt = dt_ref[0, 0].astype(jnp.float32)                     # [1, L]
-    a = a_ref[0]                                              # scalar
+    dt = dt_ref[0, 0, 0].astype(jnp.float32)                  # [L, 1]
+    a = a_ref[pl.program_id(1)]                               # scalar (SMEM)
     bmat = b_ref[0, 0, 0].astype(jnp.float32)                 # [L, N]
     cmat = c_ref[0, 0, 0].astype(jnp.float32)                 # [L, N]
 
-    da = dt[0] * a                                            # [L] (<= 0)
-    cum = jnp.cumsum(da)                                      # [L]
-    # segment-sum decay matrix: lmat[i,j] = exp(cum_i - cum_j) for j <= i
-    diff = cum[:, None] - cum[None, :]
+    da = dt * a                                               # [L, 1] (<= 0)
+    cum = doubling_prefix(da, axis=0)                         # [L, 1]
+    cum_last = cum[l_chunk - 1:]                              # [1, 1]
+    # the same value lane-broadcast first ([1, 1] -> [N, P] in one step is
+    # a broadcast Mosaic does not lower)
+    cum_last_p = jnp.broadcast_to(cum, x.shape)[l_chunk - 1:]  # [1, P]
+    # segment-sum decay matrix: lmat[i,j] = exp(cum_i - cum_j) for j <= i;
+    # the row copy of cum comes from a square transpose, which Mosaic
+    # lowers (a [L, 1] -> [1, L] relayout it does not)
+    cum_sq = jnp.broadcast_to(cum, (l_chunk, l_chunk))
+    diff = cum_sq - cum_sq.T
     tril = (jax.lax.broadcasted_iota(jnp.int32, (l_chunk, l_chunk), 0)
             >= jax.lax.broadcasted_iota(jnp.int32, (l_chunk, l_chunk), 1))
     lmat = jnp.where(tril, jnp.exp(diff), 0.0)                # [L, L]
 
-    xdt = x * dt[0][:, None]                                  # [L, P]
+    xdt = x * dt                                              # [L, P]
     scores = jax.lax.dot_general(
         cmat, bmat, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * lmat            # [L, L]
@@ -73,14 +82,14 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, h0_ref,   # inputs
     state = state_scr[...]                                    # [N, P]
     y_off = jax.lax.dot(cmat, state,
                         preferred_element_type=jnp.float32)   # [L, P]
-    y += y_off * jnp.exp(cum)[:, None]
+    y += y_off * jnp.exp(cum)
 
     # state update: h <- h * exp(cum[-1]) + B^T (xdt * decay)
-    decay = jnp.exp(cum[-1] - cum)                            # [L]
+    decay = jnp.exp(cum_last - cum)                           # [L, 1]
     contrib = jax.lax.dot_general(
-        bmat, xdt * decay[:, None], (((0,), (0,)), ((), ())),
+        bmat, xdt * decay, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)                   # [N, P]
-    state_scr[...] = state * jnp.exp(cum[-1]) + contrib
+    state_scr[...] = state * jnp.exp(cum_last_p) + contrib
 
     y_ref[0, 0, 0] = y.astype(y_ref.dtype)
 
@@ -98,8 +107,9 @@ def ssd_scan_grouped(x: jax.Array, dt: jax.Array, a: jax.Array,
     """Core pallas_call.
 
     x:  [B, H, NC, L, P]      (conv-activated inputs, head-split)
-    dt: [B, H, NC, L]         (post-softplus step sizes)
-    a:  [H]                   (negative decay coefficients)
+    dt: [B, H, NC, L, 1]      (post-softplus step sizes, one column per
+                               chunk: time runs along sublanes like x)
+    a:  [H]                   (negative decay coefficients; SMEM)
     b/c:[B, G, NC, L, N]      (G groups; heads share group blocks)
     h0: [B, H, N, P]          (initial state, zeros for training)
     Returns y: [B, H, NC, L, P] and final state [B, H, N, P] (f32).
@@ -115,8 +125,9 @@ def ssd_scan_grouped(x: jax.Array, dt: jax.Array, a: jax.Array,
         grid=(bsz, nh, nc),
         in_specs=[
             pl.BlockSpec((1, 1, 1, l, p), lambda b_, h_, c_: (b_, h_, c_, 0, 0)),
-            pl.BlockSpec((1, 1, 1, l), lambda b_, h_, c_: (b_, h_, c_, 0)),
-            pl.BlockSpec((1,), lambda b_, h_, c_: (h_,)),
+            pl.BlockSpec((1, 1, 1, l, 1),
+                         lambda b_, h_, c_: (b_, h_, c_, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, 1, l, n),
                          lambda b_, h_, c_: (b_, h_ // rep, c_, 0, 0)),
             pl.BlockSpec((1, 1, 1, l, n),

@@ -21,13 +21,18 @@ from typing import Optional
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the model shards by logical-axis constraints that GSPMD
+    # propagates (`repro.parallel.axes.constrain`); `jax.make_mesh`
+    # defaults to Explicit axes, under which those constraints and the
+    # embedding gather are refused
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1,

@@ -1,9 +1,8 @@
 """Roofline terms from dry-run artifacts + analytic model FLOPs.
 
-Hardware model (TPU v5e):
-    peak bf16 compute   197 TFLOP/s per chip
-    HBM bandwidth       819 GB/s per chip
-    ICI link bandwidth  ~50 GB/s per link
+Hardware model: `PEAKS`, keyed by the device kind JAX reports
+(``jax.devices()[0].device_kind``). A kind that is not in the table is an
+error, never a default. The dry run targets ``DRYRUN_DEVICE_KIND``.
 
 Terms per (arch x shape x mesh), all in seconds per step:
 
@@ -24,9 +23,33 @@ import dataclasses
 
 from repro.configs.base import ModelConfig, ShapeSpec
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-LINK_BW = 50e9               # bytes/s / link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    bf16_flops: float      # FLOP/s per chip
+    hbm_bw: float          # bytes/s per chip
+    link_bw: float         # bytes/s per ICI link
+    source: str
+
+
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(
+        bf16_flops=197e12, hbm_bw=819e9,
+        # 1,600 Gbit/s of chip-to-chip interconnect over 4 links
+        link_bw=50e9,
+        source='Google Cloud documentation, "TPU v5e"'),
+}
+
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """Published peak rates of one chip of ``device_kind``."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak rates for device kind {device_kind!r}; "
+                       f"add it to PEAKS with its source") from None
 
 
 # ---------------------------------------------------------------------------
@@ -169,9 +192,10 @@ def roofline_from_record(rec: dict, cfg: ModelConfig,
     for v in rec["mesh_shape"].values():
         n_dev *= v
     hlo = rec["hlo"]
-    compute = hlo["flops"] / PEAK_FLOPS
-    memory = hlo["bytes_accessed"] / HBM_BW
-    coll = hlo["total_collective_wire"] / LINK_BW
+    pk = peaks(DRYRUN_DEVICE_KIND)
+    compute = hlo["flops"] / pk.bf16_flops
+    memory = hlo["bytes_accessed"] / pk.hbm_bw
+    coll = hlo["total_collective_wire"] / pk.link_bw
     bound = max((compute, "compute"), (memory, "memory"),
                 (coll, "collective"))[1]
     mf = model_flops(cfg, shape)

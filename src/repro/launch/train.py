@@ -16,6 +16,7 @@ import json
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs.base import get_config
 from repro.configs.inputs import reduced_config
 from repro.energy.markets import generate_market
@@ -44,6 +45,7 @@ def main() -> int:
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_ckpt")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full_config:

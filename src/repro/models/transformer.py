@@ -37,8 +37,7 @@ from repro.models.attention import (AttnCache, attention_layer,
 from repro.models.layers import (_dtype, init_embeddings, init_mlp,
                                  init_rms_norm, embed, mlp, rms_norm,
                                  unembed)
-from repro.parallel.axes import (SHARD_MAP_NOCHECK, constrain,
-                                 current_mesh, shard_map)
+from repro.parallel.axes import constrain, current_mesh
 
 # ---------------------------------------------------------------------------
 # init
@@ -238,13 +237,13 @@ def moe_ffn(x: jax.Array, p: dict, cfg: ModelConfig,
             y = jax.lax.dynamic_slice_in_dim(y_all, off * bl, bl, axis=0)
             return y.reshape(bl, 1, d), aux
 
-        out, aux = shard_map(
+        out, aux = jax.shard_map(
             local_dec, mesh=mesh,
             in_specs=(xd_spec, P(None, None),
                       P(None, "data", "model"), P(None, "data", "model"),
                       P(None, "model", "data")),
             out_specs=(xd_spec, P()),
-            **SHARD_MAP_NOCHECK,
+            check_vma=False,
         )(x, p["router"], p["w1"], p["w3"], p["w2"])
         return out, aux
 
@@ -292,13 +291,13 @@ def moe_ffn(x: jax.Array, p: dict, cfg: ModelConfig,
             aux = jax.lax.pmean(aux, all_axes)
         return y.reshape(bl, sl, d), aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local, mesh=mesh,
         in_specs=(x_spec, P(None, None),
                   P("model", "data", None), P("model", "data", None),
                   P("model", None, "data")),
         out_specs=(x_spec, P()),
-        **SHARD_MAP_NOCHECK,
+        check_vma=False,
     )(x, p["router"], ep_in(p["w1"]), ep_in(p["w3"]), ep_out(p["w2"]))
     return out, aux
 

@@ -28,13 +28,6 @@ from typing import Mapping, Optional, Sequence
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                                    # jax >= 0.6: public, check_vma kw
-    shard_map = jax.shard_map
-    SHARD_MAP_NOCHECK = {"check_vma": False}
-except AttributeError:                  # older jax: experimental, check_rep
-    from jax.experimental.shard_map import shard_map
-    SHARD_MAP_NOCHECK = {"check_rep": False}
-
 LogicalRules = Mapping[str, Optional[Sequence[str] | str]]
 
 # fsdp: weights' embed dim sharded over data (ZeRO-3 style gather at use)
@@ -187,7 +180,7 @@ def named_sharding(*logical_axes: Optional[str],
 def psum_id(x, axis_name: str):
     """`jax.lax.psum` whose backward pass is the identity.
 
-    Inside a `shard_map` body (``check_rep=False``), differentiating a
+    Inside a `shard_map` body (``check_vma=False``), differentiating a
     raw ``psum`` applies ``psum`` to the cotangent too, multiplying the
     gradient by the shard count — the cotangent of a fleet aggregate is
     already replicated (every shard forms the same downstream loss from

@@ -65,7 +65,7 @@ from repro.fleet.engine import backtest, fleet_costs
 from repro.fleet.grid import concat_rows, row_chunks
 from repro.kernels.ref import fleet_scan_ref
 from repro.optim.adamw import AdamWConfig, AdamWState, adamw_update
-from repro.parallel.axes import SHARD_MAP_NOCHECK, row_mesh, shard_map
+from repro.parallel.axes import row_mesh
 from repro.tune.objective import (DispatchCoupling, PhysicalPolicy,
                                   PolicyParams, TuneProblem, cell_index,
                                   dispatch_coupling_from_grid,
@@ -616,8 +616,8 @@ def _sharded_loop(n_dev: int, cfg: TuneConfig, telemetry: bool = False):
 
     in_specs = (rows, TuneProblem(
         prices=P(), **{f: rows for f in _PROBLEM_ROW_FIELDS}))
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                   out_specs=(rows, rows, rows), **SHARD_MAP_NOCHECK)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=(rows, rows, rows), check_vma=False)
     return jax.jit(fn, donate_argnums=(0,))
 
 
@@ -644,15 +644,15 @@ def _sharded_plan_loop(n_dev: int, cfg: TuneConfig, scale_rows: int,
         coup_specs = DispatchCoupling(
             cell_id=rows, prices=P(), keys=P(), order=P(), demand=P(),
             fixed=rows, power=rows, migrate_cost=P(), cpc_ref=P())
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(rows, prob_specs, coup_specs),
-                       out_specs=(rows, rows, rows),
-                       **SHARD_MAP_NOCHECK)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(rows, prob_specs, coup_specs),
+                           out_specs=(rows, rows, rows),
+                           check_vma=False)
     else:
-        fn = shard_map(lambda r, p: body(r, p), mesh=mesh,
-                       in_specs=(rows, prob_specs),
-                       out_specs=(rows, rows, rows),
-                       **SHARD_MAP_NOCHECK)
+        fn = jax.shard_map(lambda r, p: body(r, p), mesh=mesh,
+                           in_specs=(rows, prob_specs),
+                           out_specs=(rows, rows, rows),
+                           check_vma=False)
     return jax.jit(fn, donate_argnums=(0,))
 
 
@@ -810,7 +810,7 @@ def sharded_soft_objective(raw: PolicyParams, problem: TuneProblem, tau,
     *inside* each shard's program (the same move `_sharded_plan_loop`
     makes), because reverse mode *through* `shard_map` stages the fused
     kernels' scalar residuals across the mesh, which spec inference
-    rejects under ``check_rep=False`` — and ``check_rep=True`` hits the
+    rejects under ``check_vma=False`` — and ``check_vma=True`` hits the
     known scan replication-type bug. The per-shard adjoint IS the
     global one: each shard's gradient of its *local* loss
     ``base + coupled`` w.r.t. its own rows equals the single program's
@@ -858,10 +858,10 @@ def sharded_soft_objective(raw: PolicyParams, problem: TuneProblem, tau,
     else:
         in_specs = (rows, prob_specs)
         extra = (problem,)
-    val_fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                       out_specs=P(), **SHARD_MAP_NOCHECK)
-    grad_fn = shard_map(grad_body, mesh=mesh, in_specs=in_specs,
-                        out_specs=rows, **SHARD_MAP_NOCHECK)
+    val_fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                           out_specs=P(), check_vma=False)
+    grad_fn = jax.shard_map(grad_body, mesh=mesh, in_specs=in_specs,
+                            out_specs=rows, check_vma=False)
 
     @jax.custom_vjp
     def loss(r):

@@ -160,13 +160,16 @@ class Workload:
                       **overrides) -> "Workload":
         """Derive the MW conversion from a model's analytic decode rate:
         ``batch`` sequences decoding against a ``seq_len`` cache at
-        ``mfu`` of `repro.launch.roofline.PEAK_FLOPS` on one chip."""
+        ``mfu`` of the bf16 peak of one chip of the kind the roofline
+        targets (`repro.launch.roofline.DRYRUN_DEVICE_KIND`)."""
         from repro.configs.base import ShapeSpec
-        from repro.launch.roofline import PEAK_FLOPS, model_flops
+        from repro.launch.roofline import (DRYRUN_DEVICE_KIND, model_flops,
+                                           peaks)
 
         shape = ShapeSpec("workload_decode", seq_len, batch, "decode")
         flops_per_step = model_flops(model_cfg, shape)  # batch tokens
-        tokens_per_s = batch * PEAK_FLOPS * mfu / flops_per_step
+        tokens_per_s = (batch * peaks(DRYRUN_DEVICE_KIND).bf16_flops * mfu
+                        / flops_per_step)
         overrides.setdefault("tokens_per_engine_hour",
                              tokens_per_s * 3600.0)
         return cls(**overrides)

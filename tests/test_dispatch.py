@@ -15,7 +15,7 @@ from repro.dispatch import (DispatchConfig, DispatchInfeasible,
                             resolve_demand, segment_keys, segment_rank)
 from repro.energy.markets import MarketParams
 from repro.fleet import PolicySpec, backtest, build_grid, summarize
-from repro.kernels.dispatch_scan import dispatch_scan
+from repro.kernels.dispatch_scan import dispatch_scan, time_block
 from repro.kernels.ref import dispatch_ref, fleet_scan_ref
 
 from tests._hypothesis_compat import given, settings, st
@@ -71,6 +71,20 @@ def test_dispatch_scan_bit_identical_to_ref(case):
                                    min_dwell=dwell))
     np.testing.assert_array_equal(got, want,
                                   err_msg=f"S={s} T={t} mc={mc}")
+
+
+def test_dispatch_scan_rounds_block_t_to_the_sublane_tile():
+    """A block of 100 hours is not a multiple of 8, which the TPU tiling
+    refuses; the wrapper runs 96-hour blocks instead, with the same
+    allocation."""
+    assert time_block(1000, 100) == 96 and time_block(300, 512) == 300
+    prices, avail, demand = _random_case(6, 1000)
+    order, rank = segment_rank(prices, 5.0)
+    got = np.asarray(dispatch_scan(avail, order, rank, demand,
+                                   min_dwell=2, block_t=100))
+    want = np.asarray(dispatch_ref(avail, order, rank, demand,
+                                   min_dwell=2))
+    np.testing.assert_array_equal(got, want)
 
 
 def test_dispatch_engine_paths_identical():

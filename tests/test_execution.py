@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core.tco import make_system
 from repro.dispatch import (DispatchConfig, build_problem, dispatch,
@@ -278,7 +277,7 @@ def test_fused_grads_match_oracle_and_native(case):
     np.testing.assert_array_equal(np.asarray(dt), np.asarray(ot))
     # and against native autodiff through the scan, in f64 so the
     # comparison is not dominated by f32 round-off
-    with enable_x64():
+    with jax.enable_x64(True):
         a64 = jnp.asarray(avail, jnp.float64)
         k64 = jnp.asarray(keys, jnp.float64)
         d64 = jnp.asarray(demand, jnp.float64)

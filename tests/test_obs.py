@@ -19,7 +19,6 @@ from pathlib import Path
 import jax
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro import obs
 from repro.core.tco import make_system
@@ -82,7 +81,7 @@ def test_backtest_bit_identical_on_off(tmp_path):
 
 
 def test_backtest_bit_identical_on_off_x64(tmp_path):
-    with enable_x64():
+    with jax.enable_x64(True):
         grid = _grid(t=120)
         off = backtest(grid, use_pallas=False)
         with obs.capture(tmp_path / "run"):
@@ -119,7 +118,7 @@ def test_optimize_bit_identical_on_off(tmp_path):
 
 
 def test_optimize_bit_identical_on_off_x64(tmp_path):
-    with enable_x64():
+    with jax.enable_x64(True):
         grid = _grid(t=120)
         cfg = TuneConfig(steps=6, shard=False)
         off = optimize(grid, cfg)

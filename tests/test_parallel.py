@@ -151,11 +151,10 @@ def test_compressed_pmean_under_shard_map():
             m, e2 = compressed_pmean_leaf(gs[0], es[0], "pod")
             return m[None], e2[None]
 
-        from repro.parallel.axes import SHARD_MAP_NOCHECK, shard_map
-        m, e2 = jax.jit(shard_map(
+        m, e2 = jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=(P("pod", None), P("pod", None)),
             out_specs=(P("pod", None), P("pod", None)),
-            **SHARD_MAP_NOCHECK))(g, err)
+            check_vma=False))(g, err)
         true_mean = jnp.mean(g, axis=0)
         got = m[0]
         rel = float(jnp.max(jnp.abs(got - true_mean))

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core.tco import make_system
 from repro.dispatch import (DispatchConfig, DispatchProblem, segment_keys,
@@ -169,6 +168,31 @@ def test_zero_fee_zero_dwell_reduces_to_per_hour_softmin_fill():
                                    err_msg=f"hour {h}")
 
 
+def test_newton_step_survives_a_saturated_marginal_segment():
+    """An hour met from one site's held load, which exceeds the demand by
+    one ulp (an input that `test_soft_converges_to_hard_allocation`'s
+    trajectory reaches): the level sits ~17 tau above that segment's
+    key, where the f32 sigmoid rounds to 1. The Newton step's curvature
+    must come from sigmoid(-z): with 1 - sigmoid(z) it is 0 there, the
+    step jumps to -span and the hour allocates 0.11 MW of 1.59."""
+    prices = np.array([[124.40172], [127.32521], [33.881805], [121.41237],
+                       [69.988655], [146.41827]], np.float32)   # [S, 1]
+    avail = np.array([0.42370734, 0.2899725, 2.974349, 1.6215409,
+                      1.5403848, 0.24811159], np.float32)
+    prev = np.array([0.0, 0.0, 1.5907465, 0.0, 0.0, 0.0], np.float32)
+    demand = np.float32(1.5907464)
+    keys = segment_keys(prices, 5.0)
+    order, _ = segment_rank(prices, 5.0)
+    inv_tau = jnp.float32(1e3)
+    alloc, _ = soft_dispatch_hour(
+        prev, np.zeros(6, np.float32), avail,
+        jnp.asarray(keys[0], jnp.float32), jnp.asarray(order[0]), demand,
+        inv_tau=inv_tau, inv_tau_mw=inv_tau / 0.05, min_dwell=0)
+    alloc = np.asarray(alloc)
+    assert alloc.sum() == pytest.approx(float(demand), rel=1e-6)
+    assert alloc[2] == pytest.approx(float(demand), rel=1e-5)
+
+
 def test_site_permutation_invariance():
     """Shuffling site order permutes the allocation and nothing else.
 
@@ -200,7 +224,7 @@ def test_soft_dispatch_gradients_match_fd():
     """Reverse-mode through the water level (bisection under
     stop_gradient + one differentiable Newton step) against central
     differences on availability and demand, rtol <= 1e-3 in f64."""
-    with enable_x64():
+    with jax.enable_x64(True):
         r = np.random.default_rng(3)
         s, t = 4, 24
         prices = r.normal(80, 40, (s, t))

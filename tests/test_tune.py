@@ -9,7 +9,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core.tco import make_system
 from repro.energy.markets import MarketParams
@@ -95,7 +94,7 @@ def _tiny_problem_f64(b=3, t=48):
 def test_gradients_match_finite_differences():
     """jax.grad through the associative soft scan vs central differences
     on every raw coordinate, rtol <= 1e-3 (float64)."""
-    with enable_x64():
+    with jax.enable_x64(True):
         problem = _tiny_problem_f64()
         b = problem.market_idx.shape[0]
         raw = PolicyParams(
@@ -127,7 +126,7 @@ def test_penalty_gradients_flow():
     """Fleet-coupling penalties are active and differentiable: a binding
     power cap / compute floor yields a positive penalty and finite,
     non-zero gradients."""
-    with enable_x64():
+    with jax.enable_x64(True):
         problem = _tiny_problem_f64()
 
         def loss(r):

@@ -140,6 +140,15 @@ def test_mean_demand_is_rate_conversion():
     assert 0.3 < float(np.mean(wl.mean_demand_mw(168))) < 3.0
 
 
+def test_peak_rates_are_keyed_by_device_kind():
+    from repro.launch.roofline import peaks
+    v5e = peaks("TPU v5 lite")
+    assert (v5e.bf16_flops, v5e.hbm_bw) == (197e12, 819e9)
+    assert "TPU v5e" in v5e.source
+    with pytest.raises(KeyError, match="no peak rates"):
+        peaks("cpu")
+
+
 def test_from_serving_and_from_roofline():
     from repro.serving.engine import ServeConfig
     scfg = ServeConfig()
