@@ -1,0 +1,34 @@
+"""The command refuses to run without a TPU, and without the program."""
+
+import os
+import shutil
+import subprocess
+import sys
+from conftest import ROOT
+
+ARGS = ["--workload", "hpc_mc.sweep", "--seed", str(2 ** 31 + 3),
+        "--seconds", "1", "--trace", "0"]
+
+
+def _run(cwd):
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    return subprocess.run([sys.executable, "chipbench/run.py", *ARGS],
+                          cwd=cwd, env=env, capture_output=True, text=True,
+                          timeout=300)
+
+
+def test_no_tpu_exits_nonzero_without_result():
+    p = _run(ROOT)
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""
+    assert "no TPU" in p.stderr
+
+
+def test_benchmark_files_alone_exit_nonzero(tmp_path):
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    shutil.copytree(os.path.join(ROOT, "chipbench"), tmp_path / "chipbench",
+                    ignore=shutil.ignore_patterns(".jax_cache",
+                                                  "__pycache__"))
+    p = _run(tmp_path)
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""
