@@ -34,6 +34,7 @@ from repro.fleet.grid import ScenarioGrid, concat_rows, row_chunks
 from repro.fleet.report import FleetReport
 from repro.kernels.fleet_scan import fleet_scan
 from repro.kernels.ref import FleetScanOut, fleet_hourly_ref, fleet_scan_ref
+from repro.obs.profiling import profiled
 
 
 class FleetCosts(NamedTuple):
@@ -75,7 +76,9 @@ def _backtest_jit(prices, market_idx, system_idx, policy_idx,
                   use_pallas: bool, block_b: int, block_t: int,
                   telemetry: bool = False) -> FleetReport:
     t = prices.shape[1]
-    p_rows = prices[market_idx]                       # [B, T] gather
+    with jax.named_scope("backtest.gather"):
+        p_rows = prices[market_idx]                   # [B, T] gather
+        price_sum = jnp.sum(prices, axis=1)[market_idx]   # [B] sum_t p_t
 
     if use_pallas:
         scan = fleet_scan(p_rows, p_on, p_off, off_level, idle_frac,
@@ -95,19 +98,20 @@ def _backtest_jit(prices, market_idx, system_idx, policy_idx,
                   draw_price=hourly.draw_price, starts=hourly.starts,
                   stops=hourly.stops)
 
-    price_sum = jnp.sum(prices, axis=1)[market_idx]   # [B] sum_t p_t
-    costs = fleet_costs(scan, price_sum=price_sum, fixed=fixed, power=power,
-                        period=period, restart_energy_mwh=restart_energy_mwh,
-                        restart_time_h=restart_time_h, n_samples=t)
-    return FleetReport(
-        cpc=costs.cpc, cpc_ao=costs.cpc_ao,
-        cpc_reduction=1.0 - costs.cpc / costs.cpc_ao,
-        tco=costs.tco, energy_cost=costs.energy_cost,
-        restart_cost=costs.restart_cost,
-        up_hours=costs.up_hours, n_starts=scan.n_starts,
-        x_realized=1.0 - scan.up_units / t,
-        market_idx=market_idx, system_idx=system_idx,
-        policy_idx=policy_idx)
+    with jax.named_scope("backtest.costs"):
+        costs = fleet_costs(scan, price_sum=price_sum, fixed=fixed,
+                            power=power, period=period,
+                            restart_energy_mwh=restart_energy_mwh,
+                            restart_time_h=restart_time_h, n_samples=t)
+        return FleetReport(
+            cpc=costs.cpc, cpc_ao=costs.cpc_ao,
+            cpc_reduction=1.0 - costs.cpc / costs.cpc_ao,
+            tco=costs.tco, energy_cost=costs.energy_cost,
+            restart_cost=costs.restart_cost,
+            up_hours=costs.up_hours, n_starts=scan.n_starts,
+            x_realized=1.0 - scan.up_units / t,
+            market_idx=market_idx, system_idx=system_idx,
+            policy_idx=policy_idx)
 
 
 def backtest(grid: ScenarioGrid, *, use_pallas: Optional[bool] = None,
@@ -160,12 +164,15 @@ def backtest(grid: ScenarioGrid, *, use_pallas: Optional[bool] = None,
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     telemetry = obs.enabled()
-    report = _backtest_jit(
-        grid.prices, grid.market_idx, grid.system_idx, grid.policy_idx,
-        grid.fixed, grid.power, grid.period, grid.p_on, grid.p_off,
-        grid.off_level, grid.idle_frac, grid.restart_energy_mwh,
-        grid.restart_time_h, use_pallas=bool(use_pallas),
-        block_b=block_b, block_t=block_t, telemetry=telemetry)
+    with profiled("backtest.launch", rows=grid.n_rows,
+                  hours=grid.n_hours):
+        report = _backtest_jit(
+            grid.prices, grid.market_idx, grid.system_idx,
+            grid.policy_idx, grid.fixed, grid.power, grid.period,
+            grid.p_on, grid.p_off, grid.off_level, grid.idle_frac,
+            grid.restart_energy_mwh, grid.restart_time_h,
+            use_pallas=bool(use_pallas), block_b=block_b, block_t=block_t,
+            telemetry=telemetry)
     if telemetry:
         obs.counter("fleet.backtests").inc()
         obs.trace_event("fleet.backtest", {

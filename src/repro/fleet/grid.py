@@ -28,6 +28,7 @@ import numpy as np
 from repro import execution
 from repro.core.tco import SystemCosts
 from repro.energy.markets import MarketParams, generate_market
+from repro.obs.profiling import profiled
 from repro.runtime.elastic import capacity_plan
 
 
@@ -210,7 +211,8 @@ def build_grid(markets: Union[Sequence[MarketParams], np.ndarray],
     if len(systems) == 0 or len(policies) == 0:
         raise ValueError("need at least one system and one policy")
     if isinstance(markets, (np.ndarray, jnp.ndarray)):
-        prices = np.asarray(markets, np.float32)
+        with profiled("grid.fetch", bytes=int(markets.size) * 4):
+            prices = np.asarray(markets, np.float32)
         if prices.ndim != 2:
             raise ValueError("price matrix must be [n_markets, n_hours]")
     else:
@@ -222,23 +224,41 @@ def build_grid(markets: Union[Sequence[MarketParams], np.ndarray],
     m_sys, k_pol = len(systems), len(policies)
 
     # per-(market, policy) thresholds from each market's own PV set
-    sorted_desc = -np.sort(-prices, axis=1)
+    with profiled("grid.sort", markets=n, hours=t):
+        sorted_desc = -np.sort(-prices, axis=1)
     p_off_nk = np.empty((n, k_pol), np.float32)
     p_on_nk = np.empty((n, k_pol), np.float32)
-    for k, spec in enumerate(policies):
-        for i in range(n):
-            off = _resolve_threshold(sorted_desc[i], spec)
-            p_off_nk[i, k] = off
-            p_on_nk[i, k] = _resume_threshold(off, spec.hysteresis)
+    with profiled("grid.thresholds", resolutions=n * k_pol):
+        for k, spec in enumerate(policies):
+            for i in range(n):
+                off = _resolve_threshold(sorted_desc[i], spec)
+                p_off_nk[i, k] = off
+                p_on_nk[i, k] = _resume_threshold(off, spec.hysteresis)
 
-    mi, si, pi = np.meshgrid(np.arange(n), np.arange(m_sys),
-                             np.arange(k_pol), indexing="ij")
-    mi, si, pi = (a.reshape(-1).astype(np.int32) for a in (mi, si, pi))
+    with profiled("grid.fields", rows=n * m_sys * k_pol):
+        mi, si, pi = np.meshgrid(np.arange(n), np.arange(m_sys),
+                                 np.arange(k_pol), indexing="ij")
+        mi, si, pi = (a.reshape(-1).astype(np.int32) for a in (mi, si, pi))
 
-    sys_field = lambda fn: np.asarray(  # noqa: E731
-        [float(fn(s)) for s in systems], np.float32)[si]
-    pol_field = lambda fn: np.asarray(  # noqa: E731
-        [float(fn(p)) for p in policies], np.float32)[pi]
+        sys_field = lambda fn: np.asarray(  # noqa: E731
+            [float(fn(s)) for s in systems], np.float32)[si]
+        pol_field = lambda fn: np.asarray(  # noqa: E731
+            [float(fn(p)) for p in policies], np.float32)[pi]
+        fields = dict(
+            prices=prices, market_idx=mi, system_idx=si, policy_idx=pi,
+            fixed=sys_field(lambda s: s.F),
+            power=sys_field(lambda s: s.C),
+            period=sys_field(lambda s: s.T),
+            p_on=p_on_nk[mi, pi], p_off=p_off_nk[mi, pi],
+            off_level=pol_field(lambda p: p.off_level),
+            idle_frac=pol_field(lambda p: p.idle_frac),
+            restart_energy_mwh=pol_field(lambda p: p.restart_energy_mwh),
+            restart_time_h=pol_field(lambda p: p.restart_time_h))
+
+    with profiled("grid.upload",
+                  bytes=sum(int(a.nbytes) for a in fields.values()),
+                  arrays=len(fields)):
+        fields = {k: jnp.asarray(a) for k, a in fields.items()}
 
     if market_names is None:
         market_names = tuple(f"market{i}" for i in range(n))
@@ -246,19 +266,7 @@ def build_grid(markets: Union[Sequence[MarketParams], np.ndarray],
         system_names = tuple(f"system{i}" for i in range(m_sys))
 
     return ScenarioGrid(
-        prices=jnp.asarray(prices),
-        market_idx=jnp.asarray(mi), system_idx=jnp.asarray(si),
-        policy_idx=jnp.asarray(pi),
-        fixed=jnp.asarray(sys_field(lambda s: s.F)),
-        power=jnp.asarray(sys_field(lambda s: s.C)),
-        period=jnp.asarray(sys_field(lambda s: s.T)),
-        p_on=jnp.asarray(p_on_nk[mi, pi]),
-        p_off=jnp.asarray(p_off_nk[mi, pi]),
-        off_level=jnp.asarray(pol_field(lambda p: p.off_level)),
-        idle_frac=jnp.asarray(pol_field(lambda p: p.idle_frac)),
-        restart_energy_mwh=jnp.asarray(
-            pol_field(lambda p: p.restart_energy_mwh)),
-        restart_time_h=jnp.asarray(pol_field(lambda p: p.restart_time_h)),
+        **fields,
         market_names=tuple(market_names),
         system_names=tuple(system_names),
         policy_names=tuple(p.name for p in policies),

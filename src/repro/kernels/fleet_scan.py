@@ -152,12 +152,13 @@ def fleet_scan(prices: jax.Array, p_on: jax.Array, p_off: jax.Array,
     pad_b = (-b) % block_b
     pad_t = (-t) % block_t
 
-    p_tm = jnp.pad(p.T, ((0, pad_t), (0, pad_b)))    # [T*, B*] time-major
     def _param(v):
         return jnp.pad(jnp.broadcast_to(jnp.asarray(v, jnp.float32), (b,)),
                        (0, pad_b))[None, :]
+    with jax.named_scope("fleet_scan.layout"):
+        p_tm = jnp.pad(p.T, ((0, pad_t), (0, pad_b)))  # [T*, B*] time-major
+        params = [_param(v) for v in (p_on, p_off, off_level, idle_frac)]
     out = _fleet_scan_padded(
-        p_tm, _param(p_on), _param(p_off), _param(off_level),
-        _param(idle_frac), block_b=block_b, block_t=block_t, t_total=t,
+        p_tm, *params, block_b=block_b, block_t=block_t, t_total=t,
         interpret=_auto_interpret(interpret))
     return FleetScanOut(out[0, :b], out[1, :b], out[2, :b], out[3, :b])

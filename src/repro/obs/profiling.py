@@ -1,55 +1,42 @@
-"""Profiling capture for kernel callers: wall-clock spans, XLA
-cost/memory analyses of compiled programs, and `jax.profiler.trace`
-wrapping — all landing in the same run trace (and therefore the same
-artifact schema `benchmarks/` writes).
+"""Profiling capture for kernel callers: spans on the device trace's
+clock, and XLA cost/memory analyses of compiled programs landing in the
+run trace (and therefore the same artifact schema `benchmarks/` writes).
 
-Everything here degrades to a no-op when no run is active, so call
-sites never need their own guards.
+The program never starts a profiler session: whoever runs it does (a
+benchmark, or an operator with `jax.profiler.trace`), and the spans
+land in that session beside the device's operations.
 """
 
 from __future__ import annotations
 
 import time
 from contextlib import contextmanager
-from pathlib import Path
+
+import jax
 
 from .registry import current, trace_event
 
 
 @contextmanager
 def profiled(label: str, **attrs):
-    """Time a block and emit a ``profile.span`` event. Extra keyword
-    attributes ride along in the payload (e.g. rows=..., steps=...)."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        trace_event("profile.span",
-                    {"label": label,
-                     "seconds": time.perf_counter() - t0, **attrs})
-
-
-@contextmanager
-def xla_trace(label: str):
-    """Wrap a block in `jax.profiler.trace`, writing the device trace
-    under ``<run-dir>/profile/<label>/`` (TensorBoard/Perfetto
-    loadable) and emitting a ``profile.trace`` pointer event. Yields
-    the trace directory, or None when no run is active (in which case
-    no profiler is started — profiling is never free, so it only runs
-    inside an explicit telemetry run)."""
-    run = current()
-    if run is None:
-        yield None
-        return
-    import jax
-
-    trace_dir = run.dir / "profile" / label.replace("/", "_")
-    trace_dir.mkdir(parents=True, exist_ok=True)
-    try:
-        with jax.profiler.trace(str(trace_dir)):
-            yield trace_dir
-    finally:
-        trace_event("profile.trace", {"label": label, "dir": trace_dir})
+    """A span named ``repro.<label>``: a `jax.profiler.TraceAnnotation`,
+    whose keyword attributes (e.g. rows=..., bytes=...) become the
+    event's stats in a profiler session, so a trace reads the span on
+    the same clock as the device's operations. With no session active
+    a span only checks for one (about 2.5 us an enter on a TPU v5e
+    host). When a run is active the span is also timed on the host and
+    emitted as a ``profile.span`` event carrying the same attributes."""
+    with jax.profiler.TraceAnnotation(f"repro.{label}", **attrs):
+        if current() is None:
+            yield
+            return
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            trace_event("profile.span",
+                        {"label": label,
+                         "seconds": time.perf_counter() - t0, **attrs})
 
 
 def record_compiled(label: str, compiled) -> dict:

@@ -75,7 +75,6 @@ EVENT_KINDS: dict[str, tuple[str, tuple[str, ...]]] = {
         "skip_frac", "action")),
     # profiling -----------------------------------------------------------
     "profile.span": ("obs.profiling.profiled", ("label", "seconds")),
-    "profile.trace": ("obs.profiling.xla_trace", ("label", "dir")),
     "profile.xla": ("obs.profiling.record_compiled", ("label",)),
     # benchmarks ----------------------------------------------------------
     "bench.artifact": ("benchmarks.common.write_artifact", (

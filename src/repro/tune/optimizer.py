@@ -64,6 +64,7 @@ from repro.execution import (Coupling, ExecutionPlan, take_rows,
 from repro.fleet.engine import backtest, fleet_costs
 from repro.fleet.grid import concat_rows, row_chunks
 from repro.kernels.ref import fleet_scan_ref
+from repro.obs.profiling import profiled
 from repro.optim.adamw import AdamWConfig, AdamWState, adamw_update
 from repro.parallel.axes import row_mesh
 from repro.tune.objective import (DispatchCoupling, PhysicalPolicy,
@@ -334,10 +335,11 @@ def _loop_body(raw0: PolicyParams, problem: TuneProblem, cfg: TuneConfig,
     for lo, hi in zip(bounds[:-1], bounds[1:]):
         carry, h = jax.lax.scan(step, carry, taus[lo:hi])
         hists.append(h)
-        ph = transform(carry[0])
-        cpc_rows = _hard_cpc_rows(ph.p_on, ph.p_off, ph.off_level,
-                                  problem)
-        stage_cpc.append(jnp.mean(cpc_rows))
+        with jax.named_scope("tune.stage_eval"):
+            ph = transform(carry[0])
+            cpc_rows = _hard_cpc_rows(ph.p_on, ph.p_off, ph.off_level,
+                                      problem)
+            stage_cpc.append(jnp.mean(cpc_rows))
     raw_f = carry[0]
     hist = hists[0] if len(hists) == 1 else \
         jax.tree.map(lambda *xs: jnp.concatenate(xs), *hists)
@@ -405,65 +407,71 @@ def _make_step(problem: TuneProblem, cfg: TuneConfig,
         wl.mean_demand_mw(int(problem.prices.shape[1])), jnp.float32)
 
     def step(carry, tau):
+        # XLA names a kernel after the last segment of its name stack:
+        # scoped out here the soft-scan pair stays `jvp_jit__pallas_fwd__`
+        # / `jvp_jit__pallas_bwd__`; scoped inside the differentiated
+        # function it would become `_pallas_fwd` / `_pallas_bwd`
         raw, st, lr_scale = carry
-        (loss, aux), grads = grad_fn(
-            raw, problem, tau, power_cap_mw=rc.power_cap_mw,
-            min_up_hours=rc.min_up_hours,
-            penalty_weight=rc.penalty_weight,
-            dispatch=coupling, dispatch_blend=rc.dispatch_blend,
-            dispatch_min_dwell=min_dwell,
-            dispatch_mw_scale=rc.dispatch_mw_scale,
-            dispatch_fused=cfg.fused, relief=rc.relief_config,
-            workload=wl, workload_demand=wl_demand,
-            fused=cfg.fused, block_t=cfg.block_t, reduction="sum",
-            axis_name=axis_name, scale_rows=scale_rows)
-        if axis_name is None:
-            hist_loss = loss / b
-        else:
-            # every shard's loss carries the full global coupled term;
-            # keep 1/n_sh of it so the caller's shard average (which
-            # divides the separable part by B through the b-per-shard
-            # denominators) reproduces the single program's loss/B
-            n_sh = jax.lax.psum(1, axis_name)
-            hist_loss = (loss - aux["coupled"] * (1.0 - 1.0 / n_sh)) / b
-        # finite-step guard: per-row accept mask over the row's own CPC
-        # ratio and its three gradient components
-        ok = (jnp.isfinite(aux["ratio"]) & jnp.isfinite(grads.raw_off)
-              & jnp.isfinite(grads.raw_gap)
-              & jnp.isfinite(grads.raw_lvl))                  # [B]
-        out = {"loss": hist_loss, "tau": tau,
-               "penalty": aux["penalty"],
-               "dispatch_ratio": aux["dispatch_ratio"],
-               "guard_rejects": jnp.sum((~ok).astype(jnp.float32))}
-        if telemetry:
-            # observers only: read the gradients the update consumes,
-            # feed nothing back
-            norm = jnp.sqrt(grads.raw_off ** 2 + grads.raw_gap ** 2
-                            + grads.raw_lvl ** 2)            # [B]
-            out["grad_norm"] = jnp.mean(norm)
-            out["clip_frac"] = (
-                jnp.mean((norm > cfg.clip_norm).astype(norm.dtype))
-                if cfg.clip_norm else jnp.zeros((), norm.dtype))
-        g_safe = jax.tree.map(lambda g: jnp.where(ok, g, 0.0), grads)
-        new_p, new_st = vupdate(g_safe, st, raw)
-        # backed-off rows blend toward the Adam target; where(lr == 1)
-        # selects new_p itself because raw + 1.0 * (new_p - raw) is NOT
-        # a bitwise identity
-        applied = jax.tree.map(
-            lambda n, r: jnp.where(lr_scale == 1.0, n,
-                                   r + lr_scale * (n - r)), new_p, raw)
-        raw_new = jax.tree.map(lambda a, r: jnp.where(ok, a, r),
-                               applied, raw)
-        st_new = AdamWState(
-            step=new_st.step,       # global step counts every attempt
-            mu=jax.tree.map(lambda n, o: jnp.where(ok, n, o),
-                            new_st.mu, st.mu),
-            nu=jax.tree.map(lambda n, o: jnp.where(ok, n, o),
-                            new_st.nu, st.nu))
-        lr_new = jnp.where(ok, lr_scale,
-                           jnp.maximum(lr_scale * 0.5,
-                                       _LR_BACKOFF_FLOOR))
-        return (raw_new, st_new, lr_new), out
+        with jax.named_scope("tune.objective"):
+            (loss, aux), grads = grad_fn(
+                raw, problem, tau, power_cap_mw=rc.power_cap_mw,
+                min_up_hours=rc.min_up_hours,
+                penalty_weight=rc.penalty_weight,
+                dispatch=coupling, dispatch_blend=rc.dispatch_blend,
+                dispatch_min_dwell=min_dwell,
+                dispatch_mw_scale=rc.dispatch_mw_scale,
+                dispatch_fused=cfg.fused, relief=rc.relief_config,
+                workload=wl, workload_demand=wl_demand,
+                fused=cfg.fused, block_t=cfg.block_t, reduction="sum",
+                axis_name=axis_name, scale_rows=scale_rows)
+        with jax.named_scope("tune.update"):
+            if axis_name is None:
+                hist_loss = loss / b
+            else:
+                # every shard's loss carries the full global coupled term;
+                # keep 1/n_sh of it so the caller's shard average (which
+                # divides the separable part by B through the b-per-shard
+                # denominators) reproduces the single program's loss/B
+                n_sh = jax.lax.psum(1, axis_name)
+                hist_loss = (loss - aux["coupled"] * (1.0 - 1.0 / n_sh)) / b
+            # finite-step guard: per-row accept mask over the row's own CPC
+            # ratio and its three gradient components
+            ok = (jnp.isfinite(aux["ratio"]) & jnp.isfinite(grads.raw_off)
+                  & jnp.isfinite(grads.raw_gap)
+                  & jnp.isfinite(grads.raw_lvl))                  # [B]
+            out = {"loss": hist_loss, "tau": tau,
+                   "penalty": aux["penalty"],
+                   "dispatch_ratio": aux["dispatch_ratio"],
+                   "guard_rejects": jnp.sum((~ok).astype(jnp.float32))}
+            if telemetry:
+                # observers only: read the gradients the update consumes,
+                # feed nothing back
+                norm = jnp.sqrt(grads.raw_off ** 2 + grads.raw_gap ** 2
+                                + grads.raw_lvl ** 2)            # [B]
+                out["grad_norm"] = jnp.mean(norm)
+                out["clip_frac"] = (
+                    jnp.mean((norm > cfg.clip_norm).astype(norm.dtype))
+                    if cfg.clip_norm else jnp.zeros((), norm.dtype))
+            g_safe = jax.tree.map(lambda g: jnp.where(ok, g, 0.0), grads)
+            new_p, new_st = vupdate(g_safe, st, raw)
+            # backed-off rows blend toward the Adam target; where(lr == 1)
+            # selects new_p itself because raw + 1.0 * (new_p - raw) is NOT
+            # a bitwise identity
+            applied = jax.tree.map(
+                lambda n, r: jnp.where(lr_scale == 1.0, n,
+                                       r + lr_scale * (n - r)), new_p, raw)
+            raw_new = jax.tree.map(lambda a, r: jnp.where(ok, a, r),
+                                   applied, raw)
+            st_new = AdamWState(
+                step=new_st.step,       # global step counts every attempt
+                mu=jax.tree.map(lambda n, o: jnp.where(ok, n, o),
+                                new_st.mu, st.mu),
+                nu=jax.tree.map(lambda n, o: jnp.where(ok, n, o),
+                                new_st.nu, st.nu))
+            lr_new = jnp.where(ok, lr_scale,
+                               jnp.maximum(lr_scale * 0.5,
+                                           _LR_BACKOFF_FLOOR))
+            return (raw_new, st_new, lr_new), out
 
     return step
 
@@ -501,8 +509,9 @@ def _stage_segment(carry, problem: TuneProblem,
     step = _make_step(problem, cfg, coupling, b, telemetry=telemetry)
     taus = _tau_schedule(cfg)
     carry, hist = jax.lax.scan(step, carry, taus[lo:hi])
-    ph = transform(carry[0])
-    cpc_rows = _hard_cpc_rows(ph.p_on, ph.p_off, ph.off_level, problem)
+    with jax.named_scope("tune.stage_eval"):
+        ph = transform(carry[0])
+        cpc_rows = _hard_cpc_rows(ph.p_on, ph.p_off, ph.off_level, problem)
     return carry, hist, cpc_rows
 
 
@@ -994,18 +1003,20 @@ def optimize(grid, cfg: TuneConfig = TuneConfig(), *,
     than the best swept policy of its cell under the same workload.
     """
     telemetry = obs.enabled()
-    problem = problem_from_grid(grid)
-    if warm_start is None:
-        raw0 = init_from_grid(grid)
-    elif isinstance(warm_start, TuneResult):
-        raw0 = warm_start.raw
-    elif isinstance(warm_start, PhysicalPolicy):
-        raw0 = inverse_transform(warm_start)
-    elif isinstance(warm_start, PolicyParams):
-        raw0 = warm_start
-    else:
-        raise TypeError("warm_start must be PolicyParams, PhysicalPolicy "
-                        f"or TuneResult, got {type(warm_start).__name__}")
+    with profiled("tune.problem", rows=grid.n_rows):
+        problem = problem_from_grid(grid)
+        if warm_start is None:
+            raw0 = init_from_grid(grid)
+        elif isinstance(warm_start, TuneResult):
+            raw0 = warm_start.raw
+        elif isinstance(warm_start, PhysicalPolicy):
+            raw0 = inverse_transform(warm_start)
+        elif isinstance(warm_start, PolicyParams):
+            raw0 = warm_start
+        else:
+            raise TypeError(
+                "warm_start must be PolicyParams, PhysicalPolicy or "
+                f"TuneResult, got {type(warm_start).__name__}")
     if np.asarray(raw0.raw_off).shape != (grid.n_rows,):
         raise ValueError(
             f"warm_start has {np.asarray(raw0.raw_off).shape} raw_off for "
@@ -1025,77 +1036,81 @@ def optimize(grid, cfg: TuneConfig = TuneConfig(), *,
         cfg = cfg._replace(workload=wl)
     coupling = dispatch_coupling_from_grid(grid, rc.dispatch) \
         if rc.dispatch is not None else None
-    raw_f, hist, cpc_tuned_dev = _run_loop(raw0, problem, cfg,
-                                           grid.n_rows, coupling,
-                                           telemetry)
-    stage_cpc = np.asarray(hist.pop("stage_cpc"), np.float64)
-    cpc_tuned = np.asarray(cpc_tuned_dev, np.float64)
+    with profiled("tune.loop", steps=cfg.steps):
+        raw_f, hist, cpc_tuned_dev = _run_loop(raw0, problem, cfg,
+                                               grid.n_rows, coupling,
+                                               telemetry)
+        stage_cpc = np.asarray(hist.pop("stage_cpc"), np.float64)
+        cpc_tuned = np.asarray(cpc_tuned_dev, np.float64)
 
     # hard re-evaluation of the swept baselines at tau -> 0 (chunked
     # under the same memory bound the tuning run declared)
     swept_plan = ExecutionPlan(mode="chunked", chunk_rows=chunk,
                                contract="bitwise") if chunk \
         else ExecutionPlan(mode="single")
-    swept = backtest(grid, use_pallas=False, plan=swept_plan)
-    cpc_swept = np.asarray(swept.cpc, np.float64)
+    with profiled("tune.swept", rows=grid.n_rows):
+        swept = backtest(grid, use_pallas=False, plan=swept_plan)
+        cpc_swept = np.asarray(swept.cpc, np.float64)
 
-    tuned = transform(raw_f)
-    wl_demand = None
-    wc_tuned = wc_swept = None
-    if wl is not None:
-        # the hard selection yardstick becomes the *realized* workload
-        # cost — energy + SLO deferral + VoLL drops — on one shared
-        # demand sample, so the tuned/swept comparison is paired and
-        # the selected policy can never cost more than the best swept
-        # one under the same workload
-        from repro.workload import realized_cost
-        wl_demand = wl.sample_demand_mw(grid.n_hours)
-        wc_tuned = np.asarray(realized_cost(
-            grid, tuned.p_on, tuned.p_off, tuned.off_level, wl,
-            demand_mw=wl_demand), np.float64)
-        wc_swept = np.asarray(realized_cost(
-            grid, grid.p_on, grid.p_off, grid.off_level, wl,
-            demand_mw=wl_demand), np.float64)
-        best_row = cell_best_rows(grid, wc_swept)
-    else:
-        best_row = cell_best_rows(grid, cpc_swept)
-    cpc_swept_best = cpc_swept[best_row]
+    with profiled("tune.select", rows=grid.n_rows):
+        tuned = transform(raw_f)
+        wl_demand = None
+        wc_tuned = wc_swept = None
+        if wl is not None:
+            # the hard selection yardstick becomes the *realized* workload
+            # cost — energy + SLO deferral + VoLL drops — on one shared
+            # demand sample, so the tuned/swept comparison is paired and
+            # the selected policy can never cost more than the best swept
+            # one under the same workload
+            from repro.workload import realized_cost
+            wl_demand = wl.sample_demand_mw(grid.n_hours)
+            wc_tuned = np.asarray(realized_cost(
+                grid, tuned.p_on, tuned.p_off, tuned.off_level, wl,
+                demand_mw=wl_demand), np.float64)
+            wc_swept = np.asarray(realized_cost(
+                grid, grid.p_on, grid.p_off, grid.off_level, wl,
+                demand_mw=wl_demand), np.float64)
+            best_row = cell_best_rows(grid, wc_swept)
+        else:
+            best_row = cell_best_rows(grid, cpc_swept)
+        cpc_swept_best = cpc_swept[best_row]
 
-    # cell-best swept params evaluated under *this* row's hardware
-    cb = PhysicalPolicy(p_on=grid.p_on[best_row], p_off=grid.p_off[best_row],
-                        off_level=grid.off_level[best_row])
-    cpc_cb = _hard_cpc_batched(cb.p_on, cb.p_off, cb.off_level, problem,
-                               chunk)
+        # cell-best swept params evaluated under *this* row's hardware
+        cb = PhysicalPolicy(p_on=grid.p_on[best_row],
+                            p_off=grid.p_off[best_row],
+                            off_level=grid.off_level[best_row])
+        cpc_cb = _hard_cpc_batched(cb.p_on, cb.p_off, cb.off_level,
+                                   problem, chunk)
 
-    cand = np.stack([cpc_tuned, cpc_swept, cpc_cb])        # [3, B]
-    # the selection yardstick: realized workload cost when a Workload
-    # is configured, bare hard CPC otherwise
-    yard = np.stack([wc_tuned, wc_swept, wc_swept[best_row]]) \
-        if wl is not None else cand
-    if rc.binds:
-        # fleet-coupling constraints: the swept baselines ignore them, so
-        # falling back to a lower-CPC swept policy would silently violate
-        # the constraint the user asked for — keep the tuned params.
-        # (Dispatch-aware runs likewise: a per-row swept fallback judged
-        # on *isolated* CPC would undo the fleet-role specialisation the
-        # dispatch term just taught; the swept set still competes, as a
-        # whole fleet, in the hard dispatch re-scoring below.)
-        source = np.zeros(cand.shape[1], np.int64)
-    else:
-        source = np.argmin(yard, axis=0)
-    cpc = cand[source, np.arange(cand.shape[1])]
-    workload_cost = yard[source, np.arange(yard.shape[1])] \
-        if wl is not None else None
+        cand = np.stack([cpc_tuned, cpc_swept, cpc_cb])        # [3, B]
+        # the selection yardstick: realized workload cost when a Workload
+        # is configured, bare hard CPC otherwise
+        yard = np.stack([wc_tuned, wc_swept, wc_swept[best_row]]) \
+            if wl is not None else cand
+        if rc.binds:
+            # fleet-coupling constraints: the swept baselines ignore them, so
+            # falling back to a lower-CPC swept policy would silently violate
+            # the constraint the user asked for — keep the tuned params.
+            # (Dispatch-aware runs likewise: a per-row swept fallback judged
+            # on *isolated* CPC would undo the fleet-role specialisation the
+            # dispatch term just taught; the swept set still competes, as a
+            # whole fleet, in the hard dispatch re-scoring below.)
+            source = np.zeros(cand.shape[1], np.int64)
+        else:
+            source = np.argmin(yard, axis=0)
+        cpc = cand[source, np.arange(cand.shape[1])]
+        workload_cost = yard[source, np.arange(yard.shape[1])] \
+            if wl is not None else None
 
-    def pick(tuned_v, own_v, cb_v):
-        stacked = jnp.stack([jnp.asarray(tuned_v), jnp.asarray(own_v),
-                             jnp.asarray(cb_v)])
-        return stacked[source, jnp.arange(stacked.shape[1])]
+        def pick(tuned_v, own_v, cb_v):
+            stacked = jnp.stack([jnp.asarray(tuned_v), jnp.asarray(own_v),
+                                 jnp.asarray(cb_v)])
+            return stacked[source, jnp.arange(stacked.shape[1])]
 
-    params = PhysicalPolicy(
-        p_on=pick(tuned.p_on, grid.p_on, cb.p_on),
-        p_off=pick(tuned.p_off, grid.p_off, cb.p_off),
-        off_level=pick(tuned.off_level, grid.off_level, cb.off_level))
+        params = PhysicalPolicy(
+            p_on=pick(tuned.p_on, grid.p_on, cb.p_on),
+            p_off=pick(tuned.p_off, grid.p_off, cb.p_off),
+            off_level=pick(tuned.off_level, grid.off_level, cb.off_level))
 
     dispatch_out = None
     reeval_cfg = rc.reeval_config

@@ -14,6 +14,7 @@ Regenerate the golden digest after an intentional format change with
 
 import json
 import os
+import re
 from pathlib import Path
 
 import jax
@@ -27,10 +28,11 @@ from repro.energy.presets import region_params
 from repro.energy.smard import load_price_csv
 from repro.fleet import PolicySpec, backtest, build_grid, summarize
 from repro.fleet.engine import _backtest_jit
-from repro.obs.profiling import profiled, record_compiled, xla_trace
+from repro.obs.profiling import profiled, record_compiled
 from repro.obs.report import (load_events, load_metrics,
                               reconstruct_dispatch, render_digest)
-from repro.tune import TuneConfig, optimize
+from repro.tune import (TuneConfig, init_from_grid, optimize,
+                        problem_from_grid, tune_loop)
 
 GOLDEN = Path(__file__).resolve().parent / "golden" / "obs_digest.md"
 
@@ -341,12 +343,105 @@ def test_profiling_noops_when_disabled():
     assert not obs.enabled()
     with profiled("nope"):
         pass
-    with xla_trace("nope") as d:
-        assert d is None
     compiled = jax.jit(lambda x: x + 1).lower(np.ones(4, np.float32)
                                               ).compile()
     payload = record_compiled("nope", compiled)
     assert payload["label"] == "nope"        # returns data, writes nowhere
+
+
+def _program_spans(trace_dir) -> list:
+    """(name, stats) of every ``repro.*`` host event in the one profiler
+    trace recorded under ``trace_dir``."""
+    from jax.profiler import ProfileData
+    (path,) = Path(trace_dir).rglob("*.xplane.pb")
+    data = ProfileData.from_file(str(path))
+    return [(e.name, dict(e.stats)) for plane in data.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith("repro.")]
+
+
+# span -> its attributes (the event's stats)
+PROGRAM_SPANS = {
+    "repro.grid.fetch": {"bytes"},
+    "repro.grid.sort": {"markets", "hours"},
+    "repro.grid.thresholds": {"resolutions"},
+    "repro.grid.fields": {"rows"},
+    "repro.grid.upload": {"bytes", "arrays"},
+    "repro.backtest.launch": {"rows", "hours"},
+    "repro.tune.problem": {"rows"},
+    "repro.tune.loop": {"steps"},
+    "repro.tune.swept": {"rows"},
+    "repro.tune.select": {"rows"},
+}
+
+
+def test_program_spans_land_in_a_profiler_session(tmp_path):
+    """A grid built from prices held on the device, its backtest and a
+    short `optimize`, inside an operator's own profiler session: every
+    program span lands on the host plane with its attributes."""
+    base = _grid()
+    t = base.n_hours
+    p_avg = region_params("germany", seed=0).p_avg
+    systems = [make_system(2.0 * t * 1.0 * p_avg, 1.0, float(t))]
+    policies = [PolicySpec("x5", x=0.05, off_level=0.4),
+                PolicySpec("x20", x=0.20, off_level=0.4)]
+    with jax.profiler.trace(str(tmp_path)):
+        grid = build_grid(base.prices, systems, policies)
+        backtest(grid, use_pallas=False)
+        optimize(grid, TuneConfig(steps=12))
+    spans = _program_spans(tmp_path)
+    assert {name for name, _ in spans} == set(PROGRAM_SPANS)
+    for name, stats in spans:
+        assert set(stats) == PROGRAM_SPANS[name], name
+    got = dict(spans)
+    n, k = base.n_markets, len(policies)
+    assert got["repro.grid.fetch"]["bytes"] == n * t * 4
+    assert got["repro.grid.sort"] == {"markets": n, "hours": t}
+    assert got["repro.grid.thresholds"]["resolutions"] == n * k
+    assert got["repro.grid.upload"]["arrays"] == 13
+    assert got["repro.tune.loop"]["steps"] == 12
+    # the direct backtest and the swept re-evaluation inside optimize
+    assert [name for name, _ in spans].count("repro.backtest.launch") == 2
+
+
+@pytest.mark.parametrize("program", ["backtest", "tune_loop"])
+def test_named_scopes_reach_the_op_metadata(program):
+    """The compiled programs name their phases in every operation's
+    op_name, where a device trace's metadata plane carries them."""
+    grid = _grid()
+    if program == "backtest":
+        scopes = {"backtest.gather", "backtest.costs", "fleet_scan.layout"}
+        compiled = _backtest_jit.lower(
+            grid.prices, grid.market_idx, grid.system_idx, grid.policy_idx,
+            grid.fixed, grid.power, grid.period, grid.p_on, grid.p_off,
+            grid.off_level, grid.idle_frac, grid.restart_energy_mwh,
+            grid.restart_time_h, use_pallas=True, block_b=128,
+            block_t=512).compile()
+    else:
+        scopes = {"tune.objective", "tune.update", "tune.stage_eval"}
+        compiled = tune_loop.lower(
+            init_from_grid(grid), problem_from_grid(grid), None,
+            cfg=TuneConfig(steps=12)).compile()
+    found = set()
+    for op_name in re.findall(r'op_name="([^"]*)"', compiled.as_text()):
+        # a segment under any transformation: transpose(jvp(x)) -> x
+        found.update(re.sub(r"\w+\(|\)", "", op_name).split("/"))
+    assert scopes <= found
+
+
+def test_profiled_without_a_run_annotates_and_writes_nothing(tmp_path,
+                                                             monkeypatch):
+    from repro.obs import profiling
+    written = []
+    monkeypatch.setattr(profiling, "trace_event",
+                        lambda *a: written.append(a))
+    assert not obs.enabled()
+    with jax.profiler.trace(str(tmp_path)):
+        with profiled("unit.block", rows=3):
+            pass
+    assert written == []
+    assert _program_spans(tmp_path) == [("repro.unit.block", {"rows": 3})]
 
 
 # ---------------------------------------------------------------------------
