@@ -1,12 +1,15 @@
 """Single-jit fleet backtest: every scenario row, every hour, one call.
 
 The engine replaces the per-trace Python loops of `examples/*.py` with one
-jitted pass: per-row prices are gathered from the [N, T] market block, the
-stateful hysteresis/partial-capacity scan runs batched over all B rows
-(Pallas `fleet_scan` on TPU, the pure-JAX `fleet_scan_ref` recurrence
-elsewhere), and cost accounting — restart overheads, idle draw, lost
+jitted pass: the stateful hysteresis/partial-capacity scan runs batched
+over all B rows and cost accounting — restart overheads, idle draw, lost
 restart time included — is a handful of fused [B] vector ops. A 1024-row x
-8760-hour grid is a single dispatch.
+8760-hour grid is a single dispatch. On TPU the Pallas `fleet_scan` reads
+each row's prices from the [N, T] market block by the row's market index,
+inside the kernel, so no [B, T] copy of the prices is written (only
+per-hour telemetry's companion scan gathers one); elsewhere the pure-JAX
+`fleet_scan_ref` recurrence runs over a [B, T] gather of the rows'
+prices.
 
 Row semantics match `repro.core.policy.policy_cpc` (B=1 with
 ``off_level=0`` reproduces it to float round-off). Boundary convention:
@@ -77,14 +80,18 @@ def _backtest_jit(prices, market_idx, system_idx, policy_idx,
                   telemetry: bool = False) -> FleetReport:
     t = prices.shape[1]
     with jax.named_scope("backtest.gather"):
-        p_rows = prices[market_idx]                   # [B, T] gather
         price_sum = jnp.sum(prices, axis=1)[market_idx]   # [B] sum_t p_t
 
+    def rows():
+        # [B, T] per-row prices, for the XLA scans only
+        with jax.named_scope("backtest.gather"):
+            return prices[market_idx]
+
     if use_pallas:
-        scan = fleet_scan(p_rows, p_on, p_off, off_level, idle_frac,
-                          block_b=block_b, block_t=block_t)
+        scan = fleet_scan(prices, market_idx, p_on, p_off, off_level,
+                          idle_frac, block_b=block_b, block_t=block_t)
     else:
-        scan = fleet_scan_ref(p_rows, p_on, p_off, off_level, idle_frac)
+        scan = fleet_scan_ref(rows(), p_on, p_off, off_level, idle_frac)
 
     if telemetry:
         # per-hour decision records: a *companion* scan over the same
@@ -92,7 +99,7 @@ def _backtest_jit(prices, market_idx, system_idx, policy_idx,
         # and drained once per call — it reads the report's inputs and
         # feeds nothing back, so the FleetReport bits cannot change
         # (pinned in tests/test_obs.py)
-        hourly = fleet_hourly_ref(p_rows, p_on, p_off, off_level,
+        hourly = fleet_hourly_ref(rows(), p_on, p_off, off_level,
                                   idle_frac, power)
         obs.drain("fleet.hourly", on_mw=hourly.on_mw,
                   draw_price=hourly.draw_price, starts=hourly.starts,
@@ -129,9 +136,11 @@ def backtest(grid: ScenarioGrid, *, use_pallas: Optional[bool] = None,
     layout — the same object `repro.tune.TuneConfig` takes. A chunked
     plan evaluates the grid in fixed-size row slices (via
     `ScenarioGrid.take_rows`, padded to one compile shape) instead of
-    one [B, T] pass — per-row results are identical, but the in-jit
-    price gather never exceeds the chunk footprint, which is what lets
-    `repro.tune.optimize` hard-re-evaluate B ~ 10^5 grids on one host.
+    one pass — per-row results are identical, but the XLA path's
+    in-jit [B, T] price gather never exceeds the chunk footprint, which
+    is what lets `repro.tune.optimize` hard-re-evaluate B ~ 10^5 grids
+    on one host (the Pallas path gathers no [B, T] prices; it holds
+    [B]-sized arrays and the [N, T] table alone).
     ``mode='sharded'`` raises: the backtest is a single [B, T] map with
     no coupled terms, so chunking already covers its memory story and a
     shard_map path would only add a second numerics contract.

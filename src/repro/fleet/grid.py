@@ -226,6 +226,12 @@ def build_grid(markets: Union[Sequence[MarketParams], np.ndarray],
     # per-(market, policy) thresholds from each market's own PV set
     with profiled("grid.sort", markets=n, hours=t):
         sorted_desc = -np.sort(-prices, axis=1)
+    # the sort puts +inf first and NaN and -inf last; a bad hour must stop
+    # here, since the scan reads prices through sums over markets
+    bad = ~np.isfinite(sorted_desc[:, [0, -1]]).all(axis=1)
+    if bad.any():
+        raise ValueError(f"prices must be finite: market(s) "
+                         f"{np.flatnonzero(bad).tolist()} hold NaN or inf")
     p_off_nk = np.empty((n, k_pol), np.float32)
     p_on_nk = np.empty((n, k_pol), np.float32)
     with profiled("grid.thresholds", resolutions=n * k_pol):

@@ -2,6 +2,8 @@
 single-trace paths, Pallas kernel vs reference scan, and
 permutation-invariant aggregation."""
 
+import hashlib
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -12,7 +14,7 @@ from repro.core.tco import make_system
 from repro.energy.markets import MarketParams
 from repro.fleet import (PolicySpec, backtest, build_grid, elastic_policy,
                          summarize)
-from repro.kernels.fleet_scan import fleet_scan
+from repro.kernels.fleet_scan import fleet_scan, price_planes
 from repro.kernels.ref import fleet_scan_ref
 
 rng = np.random.default_rng(7)
@@ -88,36 +90,125 @@ def test_oracle_threshold_row_attains_oracle_reduction():
 # ---------------------------------------------------------------------------
 
 FLEET_SCAN_CASES = [
-    # B, T  (exercising block padding in both axes)
-    (1, 64),
-    (5, 333),
-    (128, 512),
-    (130, 1000),
+    # N, B, T  (exercising block padding in both axes)
+    (1, 1, 64),
+    (2, 5, 333),
+    (3, 128, 512),
+    (4, 130, 1000),
 ]
 
 
-@pytest.mark.parametrize("case", FLEET_SCAN_CASES)
-def test_fleet_scan_matches_ref(case):
-    b, t = case
-    p = jnp.asarray(rng.normal(80, 40, (b, t)), jnp.float32)
+def _params(b):
     p_off = jnp.asarray(rng.uniform(40, 160, b), jnp.float32)
     p_on = p_off * jnp.asarray(rng.uniform(0.7, 1.0, b), jnp.float32)
     lvl = jnp.asarray(rng.uniform(0.0, 0.6, b), jnp.float32)
     idle = jnp.asarray(rng.uniform(0.0, 0.3, b), jnp.float32)
-    got = fleet_scan(p, p_on, p_off, lvl, idle)
-    want = fleet_scan_ref(p, p_on, p_off, lvl, idle)
+    return p_on, p_off, lvl, idle
+
+
+def _assert_scan_matches_ref(prices, idx, params, label, **kw):
+    """The kernel, reading ``prices`` by ``idx``, against the reference
+    on the gathered rows; start counts exactly."""
+    got = fleet_scan(prices, idx, *params, **kw)
+    want = fleet_scan_ref(jnp.asarray(prices)[jnp.asarray(idx)], *params)
     for name in want._fields:
         np.testing.assert_allclose(
             np.asarray(getattr(got, name)), np.asarray(getattr(want, name)),
-            rtol=2e-5, atol=1e-3, err_msg=f"{b}x{t} {name}")
+            rtol=2e-5, atol=1e-3, err_msg=f"{label} {name}")
+    np.testing.assert_array_equal(np.asarray(got.n_starts),
+                                  np.asarray(want.n_starts), err_msg=label)
+    return got
+
+
+@pytest.mark.parametrize("case", FLEET_SCAN_CASES)
+def test_fleet_scan_matches_ref(case):
+    n, b, t = case
+    p = jnp.asarray(rng.normal(80, 40, (n, t)), jnp.float32)
+    idx = jnp.asarray(rng.integers(0, n, b), jnp.int32)
+    _assert_scan_matches_ref(p, idx, _params(b), f"{n}x{b}x{t}")
+
+
+@pytest.mark.parametrize("layout", ["whatif_block", "two_lane_tiles",
+                                    "grouped_three_tiles", "ragged_time"])
+def test_fleet_scan_reads_any_market_layout(layout):
+    """Rows of mixed markets in one block (the whatif shape: 96 rows
+    over 3 markets); a market axis past one 128-lane tile, rows in random
+    order (sorted by market inside the wrapper) or grouped by market with
+    each row block spanning several tiles (run as given, one work item a
+    tile); and T not a multiple of ``block_t``."""
+    n, b, t, kw = {"whatif_block": (3, 96, 200, {}),
+                   "two_lane_tiles": (130, 150, 96, {}),
+                   "grouped_three_tiles": (300, 200, 96, {}),
+                   "ragged_time": (5, 40, 333, {"block_t": 128})}[layout]
+    p = jnp.asarray(rng.normal(80, 40, (n, t)), jnp.float32)
+    idx = rng.integers(0, n, b)
+    if layout in ("two_lane_tiles", "grouped_three_tiles"):
+        idx[:2] = (0, n - 1)             # both ends of the market axis
+    if layout == "grouped_three_tiles":
+        idx = np.sort(idx)
+    _assert_scan_matches_ref(p, jnp.asarray(idx, jnp.int32), _params(b),
+                             layout, **kw)
+
+
+@pytest.mark.parametrize("markets", ["three_presets", "two_lane_tiles"])
+def test_fleet_scan_row_order_is_free(markets):
+    """`build_grid`'s market-major rows, and the same grid shuffled by
+    `take_rows`: each row's four sums are bit-identical to its twin's,
+    within one market tile and across two (where the shuffled rows take
+    the wrapper's sort by market)."""
+    policies = [PolicySpec("ao"), PolicySpec("x3", x=0.03),
+                PolicySpec("h", x=0.05, hysteresis=0.9),
+                elastic_policy("half", level=0.5, dp_total=8, x=0.05)]
+    if markets == "three_presets":
+        grid = _grid(policies, n_markets=3,
+                     systems=(SYS, make_system(150_000.0, 1.0, float(T))))
+    else:
+        grid = build_grid(rng.normal(80, 40, (130, 200)),
+                          [make_system(60_000.0, 1.0, 200.0)], policies[1:3])
+    order = rng.permutation(grid.n_rows)
+
+    def scan(g, label):
+        return _assert_scan_matches_ref(
+            g.prices, g.market_idx,
+            (g.p_on, g.p_off, g.off_level, g.idle_frac), label)
+
+    base = scan(grid, "market-major")
+    shuf = scan(grid.take_rows(order), "shuffled")
+    for name in base._fields:
+        np.testing.assert_array_equal(np.asarray(getattr(shuf, name)),
+                                      np.asarray(getattr(base, name))[order],
+                                      err_msg=name)
+
+
+def test_price_planes_rebuild_every_price_exactly():
+    """The three bfloat16 planes are disjoint bit fields of each float32
+    price: every order of summing them, and the kernel's one-hot matmul,
+    gives back the price bit for bit."""
+    x = np.concatenate([
+        rng.normal(0, 1, 4000) * 10.0 ** rng.uniform(-20, 20, 4000),
+        [0.0, 1.0, -1.0, 3.4028235e38, -1e-30, 1e-30, 8760.123]])
+    x = jnp.asarray(x, jnp.float32)
+    hi, mid, lo = (q.astype(jnp.float32) for q in price_planes(x))
+    for total in ((hi + mid) + lo, (hi + lo) + mid, hi + (mid + lo)):
+        np.testing.assert_array_equal(np.asarray(total), np.asarray(x))
+    # read through a one-hot matmul: [T, 3N] planes @ a [3N, B] one-hot
+    tab = jnp.reshape(x[:4000], (40, 100))               # [N, T]
+    idx = jnp.asarray(rng.integers(0, 40, 64), jnp.int32)
+    planes = price_planes(tab).reshape(3 * 40, 100).T     # [T, 3N]
+    k = jnp.arange(3 * 40)[:, None] % 40
+    onehot = (k == idx[None, :]).astype(jnp.bfloat16)
+    got = jnp.dot(planes, onehot, preferred_element_type=jnp.float32)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(tab[idx].T))
 
 
 def test_fleet_scan_exact_start_count():
     """Start counting is integral — the kernel and reference must agree
     exactly, including the initial-on convention (no start at t=0)."""
     p = jnp.asarray([[50.0, 200.0, 200.0, 50.0, 200.0, 50.0, 50.0]])
-    out = fleet_scan(p, jnp.asarray([100.0]), jnp.asarray([100.0]),
-                     jnp.asarray([0.0]), jnp.asarray([0.0]))
+    out = fleet_scan(p, jnp.asarray([0]), jnp.asarray([100.0]),
+                     jnp.asarray([100.0]), jnp.asarray([0.0]),
+                     jnp.asarray([0.0]))
     assert float(out.n_starts[0]) == 2.0
     assert float(out.up_units[0]) == 4.0
 
@@ -134,6 +225,45 @@ def test_backtest_pallas_path_matches_ref_path():
         np.testing.assert_allclose(np.asarray(getattr(ref, f)),
                                    np.asarray(getattr(pal, f)),
                                    rtol=1e-5, atol=1e-5, err_msg=f)
+
+
+# First 16 hex digits of the sha256 of each `FleetReport` field on
+# `_golden_grid`, as the kernel gave them when it still scanned a gathered
+# [B, T] copy of the prices: reading prices by market index must not move
+# a bit.
+REPORT_DIGESTS = {
+    "cpc": "9e1769e426253e56",
+    "cpc_ao": "b34ba55bc2e20e3d",
+    "cpc_reduction": "b3874848570e975e",
+    "tco": "a8a1b28cc4911246",
+    "energy_cost": "471aeda03108f29f",
+    "restart_cost": "8f9dc91626a92e5c",
+    "up_hours": "c8d317346fbbce39",
+    "n_starts": "bb6374e7ee790f5d",
+    "x_realized": "11ff6014109d9ff0",
+    "market_idx": "1a8701019f0df8d1",
+    "system_idx": "bfd323c16fbeabfd",
+    "policy_idx": "42bee5b883714bc6",
+}
+
+
+def _golden_grid():
+    """130 markets (two lane tiles) x 2 systems x 3 policies, market-major."""
+    prices = np.random.default_rng(2024).normal(80, 40, (130, 300))
+    return build_grid(
+        prices,
+        [make_system(60_000.0, 1.0, 300.0), make_system(150_000.0, 2.0, 300.0)],
+        [PolicySpec("x3", x=0.03),
+         PolicySpec("h", x=0.05, hysteresis=0.9, idle_frac=0.07,
+                    restart_energy_mwh=0.4, restart_time_h=0.5),
+         elastic_policy("half", level=0.5, dp_total=8, x=0.05)])
+
+
+def test_backtest_report_bits_match_golden():
+    rep = backtest(_golden_grid(), use_pallas=True)
+    got = {f: hashlib.sha256(np.asarray(getattr(rep, f)).tobytes())
+            .hexdigest()[:16] for f in rep._fields}
+    assert got == REPORT_DIGESTS
 
 
 # ---------------------------------------------------------------------------
@@ -173,6 +303,16 @@ def test_grid_shapes_and_indexing():
     assert len(np.unique(offs)) == 3
     # always-on rows have an infinite threshold
     assert np.all(np.isinf(np.asarray(grid.p_off).reshape(3, 2, 2)[:, :, 0]))
+
+
+@pytest.mark.parametrize("bad", [np.nan, np.inf, -np.inf])
+def test_build_grid_rejects_non_finite_prices(bad):
+    """One bad hour is refused where the price matrix enters: read by
+    market index, it would spoil other markets' rows too."""
+    prices = rng.normal(80, 40, (3, 50))
+    prices[1, 17] = bad
+    with pytest.raises(ValueError, match=r"market\(s\) \[1\]"):
+        build_grid(prices, [SYS], [PolicySpec("x3", x=0.03)])
 
 
 def test_take_rows_carries_every_per_row_field():

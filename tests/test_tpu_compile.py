@@ -14,7 +14,9 @@ module is imported: only one process may load the TPU library, and test
 workers import every test file.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +24,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import get_config
+from repro.fleet.engine import _backtest_jit
+from repro.kernels import fleet_scan as fleet_scan_module
 from repro.kernels import ops
 from repro.kernels.dispatch_scan import dispatch_scan
 from repro.kernels.fleet_scan import fleet_scan
@@ -73,10 +77,41 @@ def _assert_mosaic(fn, *args):
     assert "tpu_custom_call" in text
 
 
+# the sweep cell's widths (96 market-years, 98,304 rows), whatif's
+# (3 markets, 96 rows in one mixed block), and 2,500 markets: 20 lane
+# tiles, more than a VMEM-resident market axis could hold
+FLEET_SWEEP = (96, 98_304, T)
+
+
 def test_fleet_scan_compiles(shape):
-    _assert_mosaic(
-        lambda p, a, b, c, d: fleet_scan(p, a, b, c, d, interpret=False),
-        shape(B, T), *(shape(B) for _ in range(4)))
+    for markets, rows in (FLEET_SWEEP[:2], (3, 96), (2_500, 4_096)):
+        _assert_mosaic(
+            lambda p, i, a, b, c, d: fleet_scan(p, i, a, b, c, d,
+                                                interpret=False),
+            shape(markets, T), shape(rows, dtype=jnp.int32),
+            *(shape(rows) for _ in range(4)))
+
+
+def test_backtest_holds_no_per_row_prices(shape, monkeypatch):
+    """The Pallas backtest at the sweep's widths reads prices by market
+    index: no [B, T] (or time-major [T*, B*]) float32 copy of them, which
+    alone would take 3.4 GB, comes back into the program."""
+    # the backend here is the CPU: steer the wrapper off interpret mode
+    monkeypatch.setattr(fleet_scan_module, "_auto_interpret",
+                        lambda interpret: False)
+    n, b, t = FLEET_SWEEP
+    compiled = _backtest_jit.lower(
+        shape(n, t), *(shape(b, dtype=jnp.int32) for _ in range(3)),
+        *(shape(b) for _ in range(9)),
+        use_pallas=True, block_b=128, block_t=512).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    memory = compiled.memory_analysis()
+    if memory is not None:
+        assert memory.temp_size_in_bytes < 512 * 2 ** 20
+    for dims in re.findall(r"f32\[([\d,]+)\]", text):
+        size = math.prod(int(d) for d in dims.split(","))
+        assert size < b * t, dims
 
 
 # the one-hot gathers hold [3S, 3S] in VMEM; S = 256 is the largest
