@@ -16,6 +16,7 @@ in full against the reference) and ``limits``.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from chipbench import data
@@ -109,3 +110,35 @@ class Entry:
                 "up_hours_rel": rel("up_hours"),
                 "starts_gap": float(np.max(np.abs(got["n_starts"]
                                                   - exp["n_starts"])))}
+
+
+# -- faults planted under the timed path ---------------------------------
+
+
+def _alter_first(rep, field):
+    v = getattr(rep, field)
+    return rep._replace(**{field: v.at[0].multiply(1.01)})
+
+
+def _half_rows(rep):
+    """Half of the rows left out: their answers are the mean over the
+    rest."""
+    half = rep.cpc.shape[0] // 2
+    return rep._replace(**{
+        f: getattr(rep, f).at[half:].set(jnp.mean(getattr(rep, f)[:half]))
+        for f in FIELDS})
+
+
+def _backtest_fault(alter):
+    def plant(entry):
+        real = entry._backtest
+        entry._backtest = lambda g: alter(real(g))
+        return entry
+    return plant
+
+
+FAULTS = {
+    # the first row's cost per compute off by 1 %
+    "answer_altered": _backtest_fault(lambda rep: _alter_first(rep, "cpc")),
+    "half_batch": _backtest_fault(_half_rows),
+}

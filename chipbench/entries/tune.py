@@ -24,7 +24,10 @@ Cell parameters as for ``sweep``, and ``limits``.
 
 from __future__ import annotations
 
+import contextlib
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from chipbench import data
@@ -151,3 +154,84 @@ class Entry(sweep.Entry):
         rows = self._rows(self.policies(data.WINDOW, index))
         return {**out, "cpc": self._cpc(rows, out["p_on"], out["p_off"],
                                         out["off_level"], ref.BF16)}
+
+
+# -- faults planted under the timed path ---------------------------------
+
+
+def _optimize_fault(alter):
+    """`optimize`'s result with its per-row cost per compute altered."""
+    def plant(entry):
+        real = entry._optimize
+
+        def altered(grid, cfg):
+            res = real(grid, cfg)
+            cpc = np.array(res.cpc)
+            alter(cpc)
+            return res._replace(cpc=cpc)
+
+        entry._optimize = altered
+        return entry
+    return plant
+
+
+def _alter_first(cpc):
+    cpc[0] *= 1.01
+
+
+def _half_rows(cpc):
+    """Half of the rows left out: their answers are the mean over the
+    rest."""
+    half = len(cpc) // 2
+    cpc[half:] = cpc[:half].mean()
+
+
+@contextlib.contextmanager
+def _tune_loop_replaced(keep_rows):
+    """`optimize`'s tuning loop runs as written, then every row that
+    ``keep_rows(n)`` selects gets back the parameters it started from
+    (and the loop's hard cost of them), as if the update never reached
+    it."""
+    from repro.tune import optimizer
+    loop = optimizer.tune_loop
+
+    def partial(raw0, problem, coupling=None, *, cfg, telemetry=False):
+        start = jax.tree.map(jnp.array, raw0)   # the loop donates raw0
+        raw_f, hist, _ = loop(raw0, problem, coupling, cfg=cfg,
+                              telemetry=telemetry)
+        n = start.raw_off.shape[0]
+        kept = jnp.zeros(n, bool).at[keep_rows(n)].set(True)
+        raw = jax.tree.map(lambda a, b: jnp.where(kept, a, b), start, raw_f)
+        phys = optimizer.transform(raw)
+        return raw, hist, optimizer.hard_cpc(
+            phys.p_on, phys.p_off, phys.off_level, problem)
+
+    optimizer.tune_loop = partial
+    try:
+        yield
+    finally:
+        optimizer.tune_loop = loop
+
+
+def _loop_fault(keep_rows):
+    def plant(entry):
+        real = entry._optimize
+
+        def broken(grid, cfg):
+            with _tune_loop_replaced(keep_rows):
+                return real(grid, cfg)
+
+        entry._optimize = broken
+        return entry
+    return plant
+
+
+FAULTS = {
+    # the first row's cost per compute off by 1 %
+    "answer_altered": _optimize_fault(_alter_first),
+    "half_batch": _optimize_fault(_half_rows),
+    # the tuning loop returns the state it was given
+    "state_unchanged": _loop_fault(np.arange),
+    # the update reaches only the first half of the rows
+    "half_update": _loop_fault(lambda n: np.arange(n // 2, n)),
+}

@@ -1,5 +1,6 @@
 """95th percentile of the completed calls' latency, start to results on
-the host, over every call of the window."""
+the host, over every call of the window. It reads a call of any entry,
+not the sweep's alone."""
 
 import numpy as np
 

@@ -12,12 +12,12 @@ control's (the reference computed in bfloat16, in the program's place).
 One JSON line per seed gives the worst of each number over the sampled
 calls. Benchmark runs never run the control.
 
-``--fault <kind>`` plants one of ``chipbench/faults.py``'s faults under
-the timed path and reads the program so broken. ``--program-bytes``
-prints, after the first seed's calls, the compile-time memory of the
-program the calls ran (its temporaries, which the device's peak-bytes
-counter misses); it lowers the program's private jitted functions, so
-benchmark runs never do it.
+``--fault <kind>`` plants one of the faults of the cell's entry (the
+entry module's ``FAULTS``) under the timed path and reads the program so
+broken. ``--program-bytes`` prints, after the first seed's calls, the
+compile-time memory of the program the calls ran (its temporaries, which
+the device's peak-bytes counter misses); it lowers the program's private
+jitted functions, so benchmark runs never do it.
 """
 
 from __future__ import annotations
@@ -30,7 +30,7 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from chipbench import data, faults, run  # noqa: E402
+from chipbench import data, run  # noqa: E402
 
 
 def seed_readings(workload: str, seed: int, calls: int, *,
@@ -44,10 +44,11 @@ def seed_readings(workload: str, seed: int, calls: int, *,
     module = importlib.import_module(f"chipbench.entries.{cell['entry']}")
     entry = module.Entry(config, cell, seed, run.Spans())
     if fault is not None:
-        plant, kinds = faults.BY_ENTRY[cell["entry"]]
-        if fault not in kinds:
-            raise KeyError(f"entry {cell['entry']!r} has no fault {fault!r}")
-        entry = plant(fault)(entry)
+        faults = getattr(module, "FAULTS", {})
+        if fault not in faults:
+            raise KeyError(f"entry {cell['entry']!r} has no fault {fault!r}; "
+                           f"it has {sorted(faults)}")
+        entry = faults[fault](entry)
     if wrap_entry is not None:
         entry = wrap_entry(entry)
     outs = {i: entry.call(data.WINDOW, i) for i in range(calls)}

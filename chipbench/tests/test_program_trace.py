@@ -8,11 +8,11 @@ import os
 import types
 
 import pytest
-from conftest import CELL_OVER, CONFIG_OVER, bench
 
 from chipbench import data, run
 from chipbench.program_trace import ProgramTrace, breakdown, recorded, \
     scope_segments
+from chipbench.tests.cpu_cells import bench, sizes
 from chipbench.trace import Event
 
 DEV = "/device:TPU:0"
@@ -162,8 +162,7 @@ def test_tune_readers_read_nothing_without_tune(scoped, metric):
 
 def test_recording_the_calls_after_a_window_on_cpu():
     workload = "hpc_mc.sweep"
-    _, config, cell = run.load_cell(bench(), workload, CONFIG_OVER[workload],
-                                    CELL_OVER[workload])
+    _, config, cell = run.load_cell(bench(), workload, *sizes(workload))
     module = importlib.import_module(f"chipbench.entries.{cell['entry']}")
     spans = run.Spans()
     entry = module.Entry(config, cell, 2 ** 32 + 11, spans)

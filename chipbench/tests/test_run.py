@@ -4,7 +4,8 @@ import os
 import shutil
 import subprocess
 import sys
-from conftest import ROOT
+
+from chipbench.run import ROOT
 
 ARGS = ["--workload", "hpc_mc.sweep", "--seed", str(2 ** 31 + 3),
         "--seconds", "1", "--trace", "0"]
